@@ -218,6 +218,9 @@ def main() -> None:
     import contextlib
 
     import repro
+    from repro.launch.common import use_compile_cache
+
+    use_compile_cache()
 
     with repro.profile(path=args.trace_out) if args.trace_out \
             else contextlib.nullcontext():

@@ -28,7 +28,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Tuple
 
-from jax import core
+from jax.core import DropVar
+from jax.extend import core
 
 from repro.analysis.diagnostics import Diagnostic, make
 from repro.backends.base import FallbackReason, OpSite
@@ -328,7 +329,7 @@ def lint_dead_ops(jaxpr: core.Jaxpr) -> List[Diagnostic]:
             if getattr(eqn, "effects", None):
                 continue
             outs = [v for v in eqn.outvars
-                    if not isinstance(v, core.DropVar)]
+                    if not isinstance(v, DropVar)]
             if outs and all(v not in used for v in outs):
                 agg[eqn.primitive.name] = \
                     agg.get(eqn.primitive.name, 0) + 1

@@ -39,7 +39,7 @@ import math
 import re
 from typing import Any, Dict, List, Optional, Set
 
-from jax import core
+from jax.extend import core
 
 from repro.analysis.diagnostics import Diagnostic, make
 from repro.analysis.lints import predict_fallback

@@ -45,20 +45,13 @@ import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+from jax.api_util import shaped_abstractify as _abstractify
 
 from repro.api.options import SMAOptions, resolve_options
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _obs_trace
 from repro.resilience import faults as _faults
 from repro.resilience import guard as _res_guard
-
-try:  # jax>=0.4 keeps this in api_util
-    from jax.api_util import shaped_abstractify as _abstractify
-except ImportError:  # pragma: no cover - very old jax
-    from jax import core as _core
-
-    def _abstractify(x):
-        return _core.raise_to_shaped(_core.get_aval(x))
 
 __all__ = ["Engine", "EngineStats", "sma_jit", "abstract_signature"]
 
@@ -305,6 +298,7 @@ class Engine:
                         "diagnostics", {}).get(k, 0)
                     for k in ("errors", "warnings", "infos")
                 },
+                "backends": entry.compiled.report_data.get("backends"),
             })
         return {"engine": self.name, "cache": self.stats.asdict(),
                 "entries": entries}

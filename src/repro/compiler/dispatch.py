@@ -19,7 +19,7 @@ exceptions implement the SMA execution contract:
   kernel-logic tests on CPU, ``xla`` for dry-runs);
 * batched contractions (attention q@k^T / p@v) and everything SIMD-mode
   re-bind natively — on TPU those are exactly the ops XLA places on the VPU;
-* higher-order primitives (``scan``/``while``/``cond``/``pjit``/custom-vjp
+* higher-order primitives (``scan``/``while``/``cond``/``jit``/custom-vjp
   wrappers) are re-built around recursively interpreted bodies, so GEMM
   chains *inside* layer-group scans fuse and dispatch too.
 
@@ -36,7 +36,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import core
+from jax.extend import core
 
 from repro._deprecation import warn_deprecated
 from repro.api.options import SMAOptions, options as options_context, \
@@ -229,7 +229,7 @@ class _Interpreter:
                     region_start = tracer.now_us()
             if systolic_site:
                 outvals = [self._dot(eqn, invals)]
-            elif prim == "pjit":
+            elif prim == "jit":
                 outvals = self.eval_closed(eqn.params["jaxpr"], invals)
             elif prim in ("closed_call", "core_call", "xla_call"):
                 outvals = self.eval_closed(eqn.params["call_jaxpr"], invals)
@@ -401,6 +401,11 @@ class CompiledModel:
     #: cache hits shows N, not the numbers frozen at compile time.
     report_refresh: Optional[Callable[[Dict[str, Any]], None]] = \
         dataclasses.field(default=None, repr=False, compare=False)
+    #: Under ``jit``: the XLA executable for this signature, compiled with
+    #: the rest of the pipeline (its ``memory_analysis()`` is the device
+    #: footprint of one call).  ``None`` on the interpreted path.
+    executable: Any = dataclasses.field(default=None, repr=False,
+                                        compare=False)
 
     @property
     def report(self) -> Dict[str, Any]:
@@ -513,10 +518,16 @@ def compile_with_options(fn: Callable, *args, name: Optional[str] = None,
     def runner(*flat):
         return interp.eval_closed(traced.closed_jaxpr, flat)
 
+    executable = None
     if o.jit:
         donate = _flat_donate_indices(args, kwargs, o.donate_argnums) \
             if o.donate_argnums else ()
         runner = jax.jit(runner, donate_argnums=donate)
+        # The XLA compile belongs to this signature's compile bill: done
+        # here, the first call finds it in jax.jit's own cache.
+        with _obs_trace.span("compile.xla", cat="compile"):
+            executable = runner.lower(
+                *jax.tree_util.tree_leaves((args, kwargs))).compile()
 
     report = plan_report(plan)
     report["options"] = o.asdict()
@@ -537,7 +548,8 @@ def compile_with_options(fn: Callable, *args, name: Optional[str] = None,
     report["resilience"] = _resilience_guard.resilience_section()
     compiled = CompiledModel(traced=traced, plan=plan, report_data=report,
                              _runner=runner, rewritten=rewritten, options=o,
-                             backend_records=backend_records)
+                             backend_records=backend_records,
+                             executable=executable)
     # Every compile runs the static analyzer and stamps the ``diagnostics``
     # report section (cheap: a few O(eqns) walks over structures already in
     # hand).  The ``verify`` policy only decides what error-severity

@@ -30,7 +30,7 @@ Control flow:
   loop boundary;
 * ``while`` emits its body once (trip count unknown) plus a carry marker;
 * ``cond``/``switch`` lowers the most expensive branch;
-* ``pjit`` / ``custom_jvp_call`` / ``custom_vjp_call`` / ``remat`` /
+* ``jit`` / ``custom_jvp_call`` / ``custom_vjp_call`` / ``remat`` /
   ``closed_call`` are transparent.
 """
 from __future__ import annotations
@@ -39,7 +39,7 @@ import dataclasses
 import math
 from typing import Callable, Dict, List, Optional
 
-from jax import core
+from jax.extend import core
 
 from repro.core.modes import Op, OpKind
 
@@ -115,7 +115,7 @@ _TRANSCENDENTAL_FLOPS = 4.0
 
 #: Higher-order primitives the walker recurses through transparently.
 _TRANSPARENT = {
-    "pjit": "jaxpr",
+    "jit": "jaxpr",
     "closed_call": "call_jaxpr",
     "core_call": "call_jaxpr",
     "xla_call": "call_jaxpr",

@@ -13,8 +13,8 @@ see :func:`repro.compiler.dispatch.sma_eligible`):
 
 * **epilogue chains** — ``dot → add(broadcast 1-D bias)`` and/or a named
   activation consumer: ``tanh``, ``relu`` (``max(x, 0)``, also behind
-  jax.nn's ``custom_jvp_call``/``pjit`` wrappers), ``silu``
-  (``x * logistic(x)``, inline or ``pjit[silu]``), and the tanh-approximated
+  jax.nn's ``custom_jvp_call``/``jit`` wrappers), ``silu``
+  (``x * logistic(x)``, inline or ``jit[silu]``), and the tanh-approximated
   ``gelu`` 8-equation inline chain;
 * **prologue chains** — ``rmsnorm(x; scale) → dot`` (the ``square →
   reduce_sum → div → add eps → rsqrt → mul → mul scale`` chain, with
@@ -42,7 +42,7 @@ import math
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple, Union
 
 import jax.numpy as jnp
-from jax import core
+from jax.extend import core
 
 #: dtypes the fused kernels accept for A/B (the MXU-native set).
 FUSABLE_DTYPES = frozenset({"float16", "bfloat16", "float32"})
@@ -50,7 +50,7 @@ FUSABLE_DTYPES = frozenset({"float16", "bfloat16", "float32"})
 #: higher-order primitives whose bodies the dispatcher interprets (and this
 #: pass therefore rewrites).  Mirrors ``dispatch._Interpreter``.
 _BODY_PARAMS: Dict[str, Tuple[str, ...]] = {
-    "pjit": ("jaxpr",),
+    "jit": ("jaxpr",),
     "closed_call": ("call_jaxpr",),
     "core_call": ("call_jaxpr",),
     "xla_call": ("call_jaxpr",),
@@ -251,7 +251,7 @@ def _resolve_wrapper_body(jaxpr: core.Jaxpr, args: List[Any],
 
 def _wrapper_activation(eqn: core.JaxprEqn) -> Optional[str]:
     """Match a single-input call-like equation that computes a named
-    activation *of its input* (jax.nn.relu's custom_jvp, pjit[silu], …).
+    activation *of its input* (jax.nn.relu's custom_jvp, jit[silu], …).
 
     Operand identity is checked through the wrapper nesting: ``mul(x,
     logistic(x))`` is silu, ``mul(0.5, logistic(x))`` is not.

@@ -13,7 +13,7 @@ import dataclasses
 from typing import Any, Callable
 
 import jax
-from jax import core
+from jax.extend import core
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,7 +32,14 @@ class TracedModel:
 
 
 def subjaxprs(eqn: core.JaxprEqn):
-    """Yield every (Closed)Jaxpr nested in an equation's params."""
+    """Yield every (Closed)Jaxpr nested in an equation's params.
+
+    A Pallas kernel's body is the one exception: Mosaic compiles it as a
+    unit and the dispatcher binds the ``pallas_call`` as it is, so its dots
+    are no dispatch sites and no walk looks inside.
+    """
+    if eqn.primitive.name == "pallas_call":
+        return
     for val in eqn.params.values():
         if isinstance(val, core.ClosedJaxpr):
             yield val.jaxpr

@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -77,10 +76,10 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, axis: str,
         return jax.lax.slice_in_dim(
             outs_all, (n_stages - 1) * n_micro, n_stages * n_micro, axis=0)
 
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(param_specs, P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(param_specs, P()),
+                       out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_micro)
 
 

@@ -52,7 +52,6 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.sma import EPILOGUES
@@ -285,9 +284,9 @@ def sma_gemm_sharded(a: jax.Array, b: jax.Array, *,
         acc = acc + bias_loc.astype(accum_dtype)[None, :]
         return EPILOGUES[epilogue](acc).astype(out_dtype)
 
-    fn = shard_map(summa_local, mesh=mesh,
-                   in_specs=(P(row, col), P(row, col), P(col)),
-                   out_specs=P(row, col), check_rep=False)
+    fn = jax.shard_map(summa_local, mesh=mesh,
+                       in_specs=(P(row, col), P(row, col), P(col)),
+                       out_specs=P(row, col), check_vma=False)
 
     tr = _obs_trace.current_tracer()
     if tr is None:

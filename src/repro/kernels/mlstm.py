@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _mlstm_kernel(q_ref, k_ref, v_ref, lf_ref, li_ref, o_ref,
                   c_ref, n_ref, m_ref, *,
@@ -146,7 +144,7 @@ def mlstm_chunkwise(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((1, d), jnp.float32),    # normalizer n
             pltpu.SMEM((1, 1), jnp.float32),    # stabilizer m
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, lf4, li4)

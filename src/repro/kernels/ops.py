@@ -321,8 +321,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     grouped-head SIMD path (:func:`repro.kernels.ref.paged_attention_ref`).
     """
     kn = _knobs(backend=backend, interpret=interpret)
+    # The site is the float operands only: the int32 block table would
+    # fail every kernel backend's dtype gate.
     return _guarded(
-        "paged_decode_attention", (q, k_pool, v_pool, block_table),
+        "paged_decode_attention", (q, k_pool, v_pool),
         kn["backend"], kn["interpret"],
         lambda be: lambda: be.op("paged_decode_attention")(
             q, k_pool, v_pool, block_table, q_pos, kv_len,

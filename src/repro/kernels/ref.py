@@ -24,7 +24,13 @@ def gemm_ref(a: jax.Array, b: jax.Array, *, bias: Optional[jax.Array] = None,
              epilogue: str = "none",
              accum_dtype: jnp.dtype = jnp.float32,
              precision=None) -> jax.Array:
-    """C = epilogue(A @ B + bias), accumulated in ``accum_dtype``."""
+    """C = epilogue(A @ B + bias), accumulated in ``accum_dtype``.
+
+    As in the kernel, accumulation, bias and epilogue run in at least f32:
+    a narrower ``accum_dtype`` (a bf16 einsum's preferred type) only names
+    the output rounding.
+    """
+    accum_dtype = jnp.promote_types(accum_dtype, jnp.float32)
     out = jnp.matmul(a.astype(accum_dtype), b.astype(accum_dtype),
                      precision=precision)
     if bias is not None:

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _rglru_kernel(a_ref, u_ref, h0_ref, o_ref, hlast_ref, h_ref, *,
                   block_s: int, n_s: int, out_dtype):
@@ -99,7 +97,7 @@ def rglru_scan(a: jax.Array, u: jax.Array,
             jax.ShapeDtypeStruct((b, dp), a.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, u, h0)

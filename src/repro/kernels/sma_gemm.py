@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 from repro.core.sma import EPILOGUES
 
 
@@ -148,8 +146,12 @@ def sma_gemm(a: jax.Array, b: jax.Array, *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), a.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), accum_dtype)],
-        compiler_params=_CompilerParams(
+        # Mosaic's MXU accumulates in 32 bits: a narrower ``accum_dtype``
+        # (a bf16 einsum's preferred type) only names the output rounding.
+        scratch_shapes=[pltpu.VMEM((bm, bn),
+                                   jnp.promote_types(accum_dtype,
+                                                     jnp.float32))],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*inputs)

@@ -7,6 +7,8 @@ launcher decides to materialize them.
 """
 from __future__ import annotations
 
+import os
+import pathlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -20,6 +22,29 @@ from repro.models.layers import Runtime
 from repro.optim import adamw
 
 DECODE_MARGIN = 16  # cache capacity beyond seq_len (keeps dims TP-divisible)
+
+#: The persistent compile cache used when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: ``.jax_cache`` at the root of the checkout (src/repro/launch/ is
+#: three levels below it).
+DEFAULT_COMPILE_CACHE = (pathlib.Path(__file__).resolve().parents[3]
+                         / ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a launcher process.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and this
+    sets nothing.  Otherwise the cache goes to :data:`DEFAULT_COMPILE_CACHE`,
+    a fixed path, so a later process of the same checkout finds what an
+    earlier one compiled.  Called from each launcher's ``main()``; nothing
+    turns the cache on at import.  Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(DEFAULT_COMPILE_CACHE)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------

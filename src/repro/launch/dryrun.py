@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 This proves the distribution config is coherent without TPU hardware: for the
@@ -20,10 +17,14 @@ Outputs, per cell (cached incrementally in results/dryrun/*.json):
 Usage:
     python -m repro.launch.dryrun --arch stablelm-1.6b --shape train_4k
     python -m repro.launch.dryrun --all [--mesh both] [--seq-par]
+
+``main()`` splits the host CPU into 512 devices through ``XLA_FLAGS`` before
+JAX starts its backend; importing this module changes no flag.
 """
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Dict
@@ -256,6 +257,7 @@ def cell_path(arch: str, shape: str, mesh_name: str, tag: str = "") -> str:
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="architecture id (or --all)")
     ap.add_argument("--shape", default=None, help="shape cell name")

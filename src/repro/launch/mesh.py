@@ -15,6 +15,16 @@ import jax
 FAKE_DEVICES_FLAG = "--xla_force_host_platform_device_count"
 
 
+def _auto_mesh(shape: Sequence[int], axes: Sequence[str],
+               devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: sharding stays a layout
+    hint that the compiler propagates, so slicing a sharded result and
+    ``with_sharding_constraint`` work as on an unannotated program."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """The target deployment mesh.
 
@@ -33,13 +43,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             f"but this runtime has {have}. For local/CI development use "
             f"fake_mesh(n) with XLA_FLAGS={FAKE_DEVICES_FLAG}={need} "
             f"(or smoke_mesh() for whatever devices exist).")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def smoke_mesh() -> jax.sharding.Mesh:
     """Whatever devices exist, as a 1D 'data' mesh (CPU tests)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 def _balanced_grid(n: int) -> Tuple[int, int]:
@@ -76,5 +86,4 @@ def fake_mesh(n: int, axes: Sequence[str] = ("data", "model")
             f"the process with XLA_FLAGS='{FAKE_DEVICES_FLAG}={n}' (before "
             f"jax initializes; current XLA_FLAGS={flags!r}).")
     rows, cols = _balanced_grid(n)
-    return jax.make_mesh((rows, cols), axes,
-                         devices=jax.devices()[:n])
+    return _auto_mesh((rows, cols), axes, devices=jax.devices()[:n])

@@ -38,6 +38,7 @@ import numpy as np
 from repro._deprecation import warn_deprecated
 from repro.api import SMAOptions
 from repro.configs.base import ModelConfig, get_config, reduced
+from repro.launch.common import use_compile_cache
 from repro.models import lm
 from repro.models.layers import Runtime
 from repro.obs import trace as _obs_trace
@@ -146,6 +147,7 @@ def main() -> None:
                          "write Chrome-trace JSON (Perfetto-loadable) here")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -27,6 +27,7 @@ from repro.configs.base import ModelConfig, get_config, reduced
 from repro.data.pipeline import DataConfig, DataPipeline, PipelineState
 from repro.distributed.sharding import rules_for, use_rules
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.common import use_compile_cache
 from repro.models import lm
 from repro.models.layers import Runtime
 from repro.obs import trace as _obs_trace
@@ -171,6 +172,7 @@ def main() -> None:
     ap.add_argument("--out", default=None, help="write history JSON here")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -1,7 +1,7 @@
 """AdamW optimizer with global-norm clipping and LR schedules (own impl).
 
 Functional, pytree-native, shard-transparent: optimizer state inherits the
-parameters' sharding (FSDP'd moments come for free under pjit), so ZeRO-style
+parameters' sharding (FSDP'd moments come for free under jit), so ZeRO-style
 optimizer-state sharding is a property of the parameter specs, not special
 code here.
 """

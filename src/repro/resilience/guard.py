@@ -6,7 +6,7 @@ this module supplies its policy pieces:
 
 * :func:`is_runtime_failure` — which exceptions mean "this backend cannot
   run this site right now" (retry the next rung) vs a programming error
-  (propagate).  Runtime-class: ``XlaRuntimeError`` (incl. XLA's
+  (propagate).  Runtime-class: ``JaxRuntimeError`` (incl. XLA's
   ``RESOURCE_EXHAUSTED`` / OOM texts), ``NotImplementedError``, and
   :class:`~repro.resilience.faults.InjectedFault`.
 * :func:`note_runtime_fallback` — one call per failed rung: quarantines the
@@ -30,6 +30,8 @@ import threading
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from jax.errors import JaxRuntimeError
+
 from repro.obs import metrics as _metrics
 from repro.resilience import quarantine as _quarantine
 from repro.resilience.faults import InjectedFault
@@ -41,27 +43,9 @@ __all__ = ["is_runtime_failure", "note_runtime_fallback", "next_rung",
 NUMERIC_POLICIES = ("off", "log", "raise", "fallback")
 
 #: Substrings in a RuntimeError message that mark an XLA runtime failure
-#: even when the exception type is opaque (jaxlib wraps vary by version).
+#: even when the exception type is opaque.
 _RUNTIME_MESSAGE_MARKS = ("RESOURCE_EXHAUSTED", "out of memory", "OOM",
                           "INTERNAL:", "UNIMPLEMENTED")
-
-
-def _xla_error_types() -> Tuple[type, ...]:
-    types: List[type] = []
-    try:
-        from jax.errors import JaxRuntimeError
-        types.append(JaxRuntimeError)
-    except ImportError:
-        pass
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-        types.append(XlaRuntimeError)
-    except ImportError:
-        pass
-    return tuple(types)
-
-
-_XLA_ERRORS = _xla_error_types()
 
 
 def is_runtime_failure(exc: BaseException) -> bool:
@@ -69,7 +53,7 @@ def is_runtime_failure(exc: BaseException) -> bool:
     the next backend rung (vs a programming error that must propagate)."""
     if isinstance(exc, (InjectedFault, NotImplementedError)):
         return True
-    if _XLA_ERRORS and isinstance(exc, _XLA_ERRORS):
+    if isinstance(exc, JaxRuntimeError):
         return True
     if isinstance(exc, (RuntimeError, MemoryError)):
         msg = str(exc)

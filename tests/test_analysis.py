@@ -241,7 +241,7 @@ class TestLints:
         # Tracing turns dead outputs into DropVars; SMA006 exists for
         # *rewritten* programs where a named result loses its last
         # consumer.  Model that by truncating a jaxpr's outvars.
-        from jax import core as jcore
+        from jax.extend import core as jcore
 
         jx = jax.make_jaxpr(lambda x: (jnp.sin(x), x + 1.0))(
             jnp.ones((4,), jnp.float32)).jaxpr

@@ -135,6 +135,22 @@ class TestCacheKeying:
         # the real call with the same signature reuses the entry
         engine(jnp.zeros((8, 32), jnp.float32))
         assert engine.stats.misses == 1 and engine.stats.hits == 1
+        assert compiled.executable is None   # interpreted: nothing to show
+
+    def test_jit_compile_builds_the_executable(self):
+        """Under jit the XLA compile belongs to the entry's compile: a
+        compile on shapes yields the executable and its memory analysis."""
+        w1, w2 = _mlp_weights()
+        engine = sma_jit(lambda x: jnp.tanh(x @ w1) @ w2,
+                         options=SMAOptions(backend="xla", jit=True))
+        compiled = engine.compile(jax.ShapeDtypeStruct((8, 32), jnp.float32))
+        mem = compiled.executable.memory_analysis()
+        assert mem.output_size_in_bytes == 8 * w2.shape[1] * 4
+        x = jnp.ones((8, 32), jnp.float32)
+        np.testing.assert_allclose(np.asarray(engine(x)),
+                                   np.asarray(jnp.tanh(x @ w1) @ w2),
+                                   rtol=2e-4, atol=2e-4)
+        assert engine.stats.misses == 1 and engine.stats.hits == 1
 
     def test_engine_report_and_plan_report_carry_cache_stats(self):
         w1, w2 = _mlp_weights()

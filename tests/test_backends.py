@@ -153,8 +153,7 @@ class TestCapabilityFallback:
                                    rtol=1e-4, atol=1e-4)
 
     def test_unsupported_dtype_falls_back_with_dtype_reason(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             q = jnp.ones((1, 2, 64), jnp.float64)
             kc = jnp.ones((1, 2, 8, 64), jnp.float64)
             vc = jnp.ones((1, 2, 8, 64), jnp.float64)

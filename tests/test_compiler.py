@@ -265,6 +265,22 @@ class TestDispatch:
                                    np.float32(f(x)),
                                    rtol=2e-4, atol=2e-4)
 
+    def test_kernel_body_is_no_dispatch_site(self):
+        """A Pallas kernel called from model code is bound as it is: the
+        dot inside its body is neither counted nor resolved as a site."""
+        from repro.kernels.sma_gemm import sma_gemm
+        w = jax.random.normal(KEY, (32, 128))
+
+        def f(x):
+            return sma_gemm(x, w, interpret=True)
+
+        x = jax.random.normal(jax.random.PRNGKey(4), (8, 32))
+        compiled = compiler.compile_model(f, x, backend="xla")
+        np.testing.assert_allclose(np.float32(compiled(x)),
+                                   np.float32(x @ w), rtol=2e-4, atol=2e-4)
+        assert compiled.report["dispatch"]["systolic_dispatch_sites"] == 0
+        assert compiled.report["backends"]["num_sites"] == 0
+
     def test_model_forward_dispatch_matches_native(self):
         cfg = C.reduced(C.get_config("stablelm-1.6b"))
         params, _ = lm.init(KEY, cfg)

@@ -124,14 +124,13 @@ class TestAccounting:
 
 class TestCompressedPsum:
     def test_matches_uncompressed_mean_single_device(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         devs = np.array(jax.devices()[:1])
         mesh = Mesh(devs, ("dp",))
         x = jax.random.normal(KEY, (len(devs), 64), jnp.float32)
 
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             lambda v: compressed_psum(v, "dp"),
             mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(x)
         mean = np.asarray(x).mean(axis=0)

@@ -179,13 +179,12 @@ def test_pipeline_parallel_4_stages():
 def test_compressed_psum_accuracy():
     out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.optim.compress import compressed_psum
         mesh = Mesh(np.array(jax.devices()).reshape(4), ('dp',))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
-        f = shard_map(lambda t: compressed_psum(t, 'dp'), mesh=mesh,
-                      in_specs=P('dp'), out_specs=P('dp'), check_rep=False)
+        f = jax.shard_map(lambda t: compressed_psum(t, 'dp'), mesh=mesh,
+                          in_specs=P('dp'), out_specs=P('dp'), check_vma=False)
         got = f(g)
         want = jnp.broadcast_to(jnp.mean(g, 0, keepdims=True), g.shape)
         err = float(jnp.max(jnp.abs(got - want)))
@@ -209,7 +208,8 @@ def test_dryrun_machinery_small_mesh():
         import dataclasses
         cfg = dataclasses.replace(C.reduced(C.get_config('stablelm-1.6b')),
                                   num_groups=2)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import fake_mesh
+        mesh = fake_mesh(8)
         shape = ShapeConfig('tiny_train', seq_len=64, global_batch=8,
                             kind='train')
         fn, args = build_cell(cfg, shape, mesh)
@@ -236,7 +236,8 @@ def test_dryrun_decode_small_mesh():
         from repro.configs.base import ShapeConfig
         cfg = dataclasses.replace(C.reduced(C.get_config('mistral-nemo-12b')),
                                   num_groups=2)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import fake_mesh
+        mesh = fake_mesh(8)
         shape = ShapeConfig('tiny_decode', seq_len=128, global_batch=8,
                             kind='decode')
         fn, args = build_cell(cfg, shape, mesh)

@@ -52,6 +52,25 @@ def test_sma_gemm_allclose(m, k, n, ep, bias, dtype):
     assert_close(got, want, dtype)
 
 
+def test_sma_gemm_narrow_accum_matches_ref():
+    """A bf16 ``accum_dtype`` (a bf16 einsum's preferred type) names only
+    the output rounding: kernel and reference both accumulate, add the
+    bias and apply the epilogue in f32, so they differ by at most one bf16
+    rounding."""
+    ks = jax.random.split(KEY, 3)
+    a = jax.random.normal(ks[0], (64, 512), jnp.bfloat16)
+    b = jax.random.normal(ks[1], (512, 256), jnp.bfloat16)
+    bias = jax.random.normal(ks[2], (256,), jnp.bfloat16)
+    kw = dict(bias=bias, epilogue="gelu", accum_dtype=jnp.bfloat16)
+    got = np.float32(sma_gemm(a, b, interpret=True, block_m=64,
+                              block_n=128, block_k=128, **kw))
+    want = np.float32(ref.gemm_ref(a, b, **kw))
+    # One bf16 ulp, plus f32 summation-order noise near zero.
+    bound = (2.0 ** -7 * np.maximum(np.abs(got), np.abs(want))
+             + 1e-5 * np.abs(want).max())
+    assert np.all(np.abs(got - want) <= bound)
+
+
 def test_sma_gemm_batched_leading_dims():
     a = jax.random.normal(KEY, (2, 3, 64, 128), jnp.float32)
     b = jax.random.normal(KEY, (128, 96), jnp.float32)

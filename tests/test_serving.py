@@ -134,14 +134,14 @@ class TestPagedAttentionOp:
         out = np.einsum("bchgl,blhd->bchgd", p, v.astype(np.float32))
         return out.reshape(b, c, hq, d)
 
-    @pytest.mark.parametrize("c,window", [(1, None), (4, None), (4, 8)])
-    def test_matches_dense_oracle(self, c, window):
+    @staticmethod
+    def _case(c):
+        """Random q and dense k/v, with k/v also scattered into a pool."""
         rng = np.random.RandomState(0)
         b, hq, hkv, d, bs, nb, mb = 2, 4, 2, 16, 4, 12, 4
         kv_len = np.array([6, 11], np.int32)
         q_pos = (kv_len - c)[:, None] + np.arange(c)[None, :]
         q = rng.randn(b, c, hq, d).astype(np.float32)
-        # build dense k/v then scatter into the paged pool
         dense_k = rng.randn(b, mb * bs, hkv, d).astype(np.float32)
         dense_v = rng.randn(b, mb * bs, hkv, d).astype(np.float32)
         k_pool = np.zeros((nb, hkv, bs, d), np.float32)
@@ -154,12 +154,33 @@ class TestPagedAttentionOp:
                 k_pool[nxt] = dense_k[r, j * bs:(j + 1) * bs].swapaxes(0, 1)
                 v_pool[nxt] = dense_v[r, j * bs:(j + 1) * bs].swapaxes(0, 1)
                 nxt += 1
+        return q, dense_k, dense_v, k_pool, v_pool, table, q_pos, kv_len
+
+    @pytest.mark.parametrize("c,window", [(1, None), (4, None), (4, 8)])
+    def test_matches_dense_oracle(self, c, window):
+        q, dense_k, dense_v, k_pool, v_pool, table, q_pos, kv_len = \
+            self._case(c)
         got = kops.paged_decode_attention(
             jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(table), jnp.asarray(q_pos), jnp.asarray(kv_len),
             window=window, backend="xla")
         want = self._dense_oracle(q, dense_k, dense_v, q_pos, kv_len,
                                   window)
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+
+    def test_kernel_backend_takes_decode_sites(self):
+        """The int32 block table is no operand of the site, so a
+        single-token site passes the kernel backends' dtype gate."""
+        from repro.backends import registry
+        q, dense_k, dense_v, k_pool, v_pool, table, q_pos, kv_len = \
+            self._case(1)
+        with registry.record_sites() as sites:
+            got = kops.paged_decode_attention(
+                jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+                jnp.asarray(table), jnp.asarray(q_pos), jnp.asarray(kv_len),
+                backend="interpret")
+        assert [s["backend"] for s in sites] == ["interpret"]
+        want = self._dense_oracle(q, dense_k, dense_v, q_pos, kv_len)
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
     def test_sentinel_rows_stay_finite(self):

@@ -83,7 +83,8 @@ class TestCheckpoint:
         mgr = CheckpointManager(str(tmp_path), async_save=False)
         tree = {"w": jnp.arange(16.0).reshape(4, 4)}
         mgr.save(1, tree)
-        mesh = jax.make_mesh((1,), ("data",))
+        from repro.launch.mesh import smoke_mesh
+        mesh = smoke_mesh()
         sh = jax.sharding.NamedSharding(mesh,
                                         jax.sharding.PartitionSpec("data"))
         step, restored = mgr.restore(tree, shardings={"w": sh})
@@ -342,3 +343,28 @@ class TestServingEdgeCases:
         while server.active:
             server.tick()
         assert all(0 <= t < lm.padded_vocab(cfg) for t in req.out_tokens)
+
+
+# ------------------------------------------------------------ compile cache
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        from repro.launch.common import use_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_one_fixed_dir_in_the_checkout(self, monkeypatch):
+        from repro.launch.common import use_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        first = use_compile_cache()
+        assert use_compile_cache() == first
+        assert jax.config.jax_compilation_cache_dir == first
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(root, ".jax_cache")
