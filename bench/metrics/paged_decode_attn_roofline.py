@@ -1,0 +1,20 @@
+"""Kernels: least time of the traced decode ticks' attention (K and V up
+to each row's live ``kv_len``, plus q and out) over the device time of the
+decode-attention Pallas calls in the trace.  The page gather that runs
+before the kernel is not in the kernel's time."""
+from bench import work
+
+#: How the trace names the decode-attention Pallas calls.
+KERNEL = r"^%closed_call(\.\d+)? = .*tpu_custom_call"
+
+
+def read(rec):
+    tr = rec.trace
+    if tr is None:
+        return None
+    t_kernel = tr.op_seconds(KERNEL)
+    if not t_kernel:
+        return None
+    least = sum(work.attention_least_time(rec.model, t.kv_lens, rec.peaks)
+                for t in rec.traced_ticks if t.phase == "decode")
+    return 100.0 * least / t_kernel
