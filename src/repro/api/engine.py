@@ -243,8 +243,11 @@ class Engine:
         done via a re-lookup with an ``xla`` overlay, which compiles (and
         caches) its own entry and never recurses further.
         """
-        entry, dyn_kwargs, hit, opts = self._lookup(args, kwargs, overlay)
-        out = entry.compiled(*args, **dyn_kwargs)
+        with _obs_trace.span("engine.lookup", cat="engine"):
+            entry, dyn_kwargs, hit, opts = self._lookup(args, kwargs,
+                                                        overlay)
+        with _obs_trace.span("engine.dispatch", cat="engine"):
+            out = entry.compiled(*args, **dyn_kwargs)
         policy = opts.check_numerics
         if policy in (None, "off"):
             return out, hit
@@ -257,14 +260,16 @@ class Engine:
         return out, hit
 
     def __call__(self, *args, **kwargs):
-        tracer = _obs_trace.current_tracer()
-        if tracer is None:
+        if (_obs_trace.current_tracer() is None
+                and not _obs_trace.recording()):
             return self._run(args, kwargs)[0]
-        with tracer.span("engine.call", cat="engine",
-                         engine=self.name) as sp:
+        with _obs_trace.span("engine.call", cat="engine",
+                             engine=self.name) as sp:
             out, hit = self._run(args, kwargs)
-            sp.annotate(cache="hit" if hit else "miss")
-            return sp.block(out)
+            if sp is not None:      # None: the profiler stopped meanwhile
+                sp.annotate(cache="hit" if hit else "miss")
+                out = sp.block(out)
+            return out
 
     def compile(self, *args, **kwargs):
         """Compile (or fetch) the executable for this signature WITHOUT

@@ -31,12 +31,14 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import re
 import warnings
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.extend import core
+from jax.extend import source_info_util
 
 from repro._deprecation import warn_deprecated
 from repro.api.options import SMAOptions, options as options_context, \
@@ -163,6 +165,17 @@ def collect_comm_sites(jaxpr: core.Jaxpr,
 # --------------------------------------------------------------------------
 # The interpreter
 # --------------------------------------------------------------------------
+def _scope(source_info):
+    """Bind under an equation's recorded name stack, as ``jax.core``'s own
+    ``eval_jaxpr`` does: the model's ``jax.named_scope`` paths (and those
+    of the kernel sites traced inside it) then survive re-execution and
+    name the compiled program's ops."""
+    return source_info_util.user_context(
+        source_info.traceback,
+        name_stack=source_info_util.current_name_stack()
+        + source_info.name_stack)
+
+
 class _Interpreter:
     def __init__(self, options: SMAOptions,
                  rewrite: Optional[RewriteResult] = None) -> None:
@@ -216,8 +229,9 @@ class _Interpreter:
         for eqn in items:
             if isinstance(eqn, FusedGemm):
                 flush_region()
-                write(eqn.outvar,
-                      self._fused(eqn, [read(v) for v in eqn.invars]))
+                with _scope(eqn.source_info):
+                    write(eqn.outvar,
+                          self._fused(eqn, [read(v) for v in eqn.invars]))
                 continue
             invals = [read(v) for v in eqn.invars]
             prim = eqn.primitive.name
@@ -227,36 +241,39 @@ class _Interpreter:
                     flush_region()
                 elif region_start is None:
                     region_start = tracer.now_us()
-            if systolic_site:
-                outvals = [self._dot(eqn, invals)]
-            elif prim == "jit":
-                outvals = self.eval_closed(eqn.params["jaxpr"], invals)
-            elif prim in ("closed_call", "core_call", "xla_call"):
-                outvals = self.eval_closed(eqn.params["call_jaxpr"], invals)
-            elif prim in ("remat", "checkpoint"):
-                outvals = self.eval(eqn.params["jaxpr"], (), invals)
-            elif prim in ("custom_jvp_call", "custom_vjp_call"):
-                outvals = self._closed_or_open(eqn.params["call_jaxpr"],
-                                               invals)
-            elif prim in ("custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr"):
-                outvals = self._closed_or_open(eqn.params["fun_jaxpr"],
-                                               invals)
-            elif prim == "scan":
-                outvals = self._scan(eqn, invals)
-            elif prim == "while":
-                outvals = self._while(eqn, invals)
-            elif prim == "cond":
-                outvals = self._cond(eqn, invals)
-            else:
-                out = eqn.primitive.bind(*invals, **eqn.params)
-                outvals = list(out) if eqn.primitive.multiple_results \
-                    else [out]
+            with _scope(eqn.source_info):
+                outvals = self._bind(eqn, prim, systolic_site, invals)
             if tracer is not None and not systolic_site:
                 region_eqns += 1
             for var, val in zip(eqn.outvars, outvals):
                 write(var, val)
         flush_region()
         return [read(v) for v in jaxpr.outvars]
+
+    def _bind(self, eqn, prim: str, systolic_site: bool, invals):
+        """Execute one equation: systolic GEMM sites through the kernel
+        entry point, higher-order primitives around interpreted bodies,
+        everything else re-bound as traced."""
+        if systolic_site:
+            return [self._dot(eqn, invals)]
+        if prim == "jit":
+            return self.eval_closed(eqn.params["jaxpr"], invals)
+        if prim in ("closed_call", "core_call", "xla_call"):
+            return self.eval_closed(eqn.params["call_jaxpr"], invals)
+        if prim in ("remat", "checkpoint"):
+            return self.eval(eqn.params["jaxpr"], (), invals)
+        if prim in ("custom_jvp_call", "custom_vjp_call"):
+            return self._closed_or_open(eqn.params["call_jaxpr"], invals)
+        if prim in ("custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr"):
+            return self._closed_or_open(eqn.params["fun_jaxpr"], invals)
+        if prim == "scan":
+            return self._scan(eqn, invals)
+        if prim == "while":
+            return self._while(eqn, invals)
+        if prim == "cond":
+            return self._cond(eqn, invals)
+        out = eqn.primitive.bind(*invals, **eqn.params)
+        return list(out) if eqn.primitive.multiple_results else [out]
 
     def _closed_or_open(self, jx, invals):
         if isinstance(jx, core.ClosedJaxpr):
@@ -517,6 +534,9 @@ def compile_with_options(fn: Callable, *args, name: Optional[str] = None,
 
     def runner(*flat):
         return interp.eval_closed(traced.closed_jaxpr, flat)
+
+    # jax.jit names the XLA module after the function: jit_<engine name>.
+    runner.__name__ = re.sub(r"\W", "_", traced.name)
 
     executable = None
     if o.jit:

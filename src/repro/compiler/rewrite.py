@@ -91,6 +91,9 @@ class FusedGemm:
     eqns_elided: int = 0
     hbm_bytes_avoided: float = 0.0
     site: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: The anchor dot's ``source_info``: the fused call binds under its
+    #: name stack, so the kernel keeps the model's scope.
+    source_info: Any = None
 
 
 @dataclasses.dataclass
@@ -734,7 +737,8 @@ class _Rewriter:
                            preferred_element_type=dot_eqn.params.get(
                                "preferred_element_type"),
                            eqns_elided=len(chain) - 1,
-                           hbm_bytes_avoided=bytes_avoided, site=site_info)
+                           hbm_bytes_avoided=bytes_avoided, site=site_info,
+                           source_info=dot_eqn.source_info)
             self.stats.realized_prologue_sites += 1
         else:
             invars = (a, b, bias) if bias is not None else (a, b)
@@ -745,7 +749,8 @@ class _Rewriter:
                            preferred_element_type=dot_eqn.params.get(
                                "preferred_element_type"),
                            eqns_elided=len(chain) - 1,
-                           hbm_bytes_avoided=bytes_avoided, site=site_info)
+                           hbm_bytes_avoided=bytes_avoided, site=site_info,
+                           source_info=dot_eqn.source_info)
             self.stats.realized_epilogue_sites += 1
 
         self.stats.realized_fused_sites += 1

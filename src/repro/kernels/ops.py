@@ -127,16 +127,18 @@ def _guarded(op: str, site_args: Tuple[Any, ...], backend: Any,
 
 
 def _launch(op: str, be: _base.Backend, call, **attrs: Any):
-    """Run one kernel launch, recording a span when a profile scope is
-    active.  The span is tagged with the resolved :class:`ExecMode` and
-    backend so the exported trace lands on the right systolic/SIMD lane;
-    ``attrs`` carries the launch-shaping decisions (block sizes, autotune)."""
-    tr = _obs_trace.current_tracer()
-    if tr is None:
-        return call()
-    with tr.span(f"kernel.{op}", cat="kernel", mode=be.mode.value,
-                 backend=be.name, **attrs) as sp:
-        return sp.block(call())
+    """Run one kernel launch under the name scope ``op``, so every device
+    op the launch emits (a page gather, a re-layout, the kernel itself)
+    carries the site's name in the compiled program's metadata.  A span
+    is recorded when a profile scope or profiler session is active,
+    tagged with the resolved :class:`ExecMode` and backend so the exported
+    trace lands on the right systolic/SIMD lane; ``attrs`` carries the
+    launch-shaping decisions (block sizes, autotune)."""
+    with jax.named_scope(op), _obs_trace.span(
+            f"kernel.{op}", cat="kernel", mode=be.mode.value,
+            backend=be.name, **attrs) as sp:
+        out = call()
+        return out if sp is None else sp.block(out)
 
 
 def _mesh_routable(a: jax.Array, b: jax.Array, mesh: Any) -> bool:

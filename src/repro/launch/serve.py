@@ -14,7 +14,9 @@ configured for slot-equivalent behaviour —
   request and re-feeds the last prompt token first (the old first-tick
   semantics) — outputs are tick-for-tick compatible;
 * the same fault sites (``serve.admit`` / ``serve.tick``), ``serve.*``
-  counters, retry/evict/watchdog behaviour, and legacy trace span names.
+  counters, retry/evict/watchdog behaviour, and the ``serve.admit`` /
+  ``serve.warmup`` spans (a tick is the engine's own
+  ``serving.tick.decode`` span).
 
 Each ``Server`` construction emits one :class:`DeprecationWarning` pointed
 at the caller.  Migrate to::
@@ -129,9 +131,7 @@ class Server:
         """Decode one token for every active request."""
         if not self.core.active:
             return {}
-        with _obs_trace.span("serve.tick", cat="serve",
-                             active=len(self.core.active)):
-            return self.core.decode_tick()
+        return self.core.decode_tick()
 
 
 def main() -> None:

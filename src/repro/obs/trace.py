@@ -8,8 +8,16 @@ the serving/training drivers' steps.  The contract:
 
 * **Strictly off by default.**  No tracer is installed unless the program is
   inside a :func:`profile` scope; every instrumentation site reduces to one
-  ``ContextVar.get()`` returning ``None`` plus a no-op context manager, so
-  disabled tracing costs nanoseconds per site and records nothing.
+  ``ContextVar.get()`` returning ``None``, one check that no JAX profiler
+  session is recording, and a shared no-op context manager, so disabled
+  tracing costs well under a microsecond per site and records nothing.
+* **On the profiler's clock.**  Whenever a JAX profiler session is
+  recording (``jax.profiler.trace`` / ``start_trace``, or an xprof capture),
+  every span also opens a ``jax.profiler.TraceAnnotation`` of the same name
+  carrying its scalar arguments, with or without a :func:`profile` scope.
+  The program's spans then sit in the profiler trace beside the device ops
+  they launched, so each idle gap on the device can be attributed to the
+  host work that was running.
 * **Never part of the compile-cache key.**  Tracing state lives in a
   contextvar here, NOT in :class:`repro.api.options.SMAOptions` — enabling a
   profile can never fragment the engine's executable cache (asserted in
@@ -36,8 +44,42 @@ import contextvars
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Span", "Tracer", "profile", "span", "current_tracer",
-           "last_tracer"]
+           "last_tracer", "recording"]
+
+#: True while a JAX profiler session is recording (a C++ flag read).
+recording = TraceAnnotation.is_enabled
+
+
+def _scalars(args: Dict[str, Any]) -> Dict[str, Any]:
+    """The arguments a profiler event can carry: numbers and strings."""
+    return {k: v for k, v in args.items()
+            if isinstance(v, (int, float, str))}
+
+
+class _Annotation:
+    """A span seen only by the JAX profiler (no :func:`profile` scope is
+    active): the same ``annotate``/``block`` surface as :class:`Span`."""
+
+    __slots__ = ("_me",)
+
+    def __init__(self, name: str, args: Dict[str, Any]) -> None:
+        self._me = TraceAnnotation(name, **_scalars(args))
+
+    def __enter__(self) -> "_Annotation":
+        self._me.__enter__()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self._me.__exit__(*exc)
+
+    def annotate(self, **attrs: Any) -> None:
+        self._me.set_metadata(**_scalars(attrs))
+
+    def block(self, value: Any) -> Any:
+        return value
 
 
 class Span:
@@ -45,7 +87,8 @@ class Span:
     tracer's event list (as a Chrome-trace-shaped dict) when the ``with``
     scope exits."""
 
-    __slots__ = ("tracer", "name", "cat", "mode", "args", "_start")
+    __slots__ = ("tracer", "name", "cat", "mode", "args", "_start",
+                 "_profiled")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  mode: Optional[str], args: Dict[str, Any]) -> None:
@@ -55,6 +98,7 @@ class Span:
         self.mode = mode
         self.args = args
         self._start = 0.0
+        self._profiled: Optional[_Annotation] = None
 
     @property
     def sync(self) -> bool:
@@ -63,6 +107,8 @@ class Span:
     def annotate(self, **attrs: Any) -> None:
         """Attach attributes discovered mid-span (e.g. cache hit/miss)."""
         self.args.update(attrs)
+        if self._profiled is not None:
+            self._profiled.annotate(**attrs)
 
     def block(self, value: Any) -> Any:
         """Synchronize on ``value`` at the span boundary when the tracer is
@@ -116,11 +162,15 @@ class Tracer:
     def span(self, name: str, *, cat: str = "host",
              mode: Optional[str] = None, **args: Any) -> Iterator[Span]:
         sp = Span(self, name, cat, mode, dict(args))
+        if recording():
+            sp._profiled = _Annotation(name, args).__enter__()
         sp._start = self.now_us()
         try:
             yield sp
         finally:
             end = self.now_us()
+            if sp._profiled is not None:
+                sp._profiled.__exit__(None, None, None)
             if not self.sync:
                 sp.args.setdefault("synced", False)
             self.events.append({"name": sp.name, "cat": sp.cat,
@@ -215,13 +265,16 @@ _NULL = contextlib.nullcontext()
 
 def span(name: str, *, cat: str = "host", mode: Optional[str] = None,
          **args: Any):
-    """``with obs.span(...) as sp`` — records iff a profile is active.
+    """``with obs.span(...) as sp`` — records iff a profile is active, and
+    lands in the JAX profiler's trace iff a profiler session is recording.
 
-    Disabled cost is one contextvar read plus a shared ``nullcontext``;
-    ``sp`` is ``None`` when disabled, so conditional annotations read
-    ``if sp is not None: sp.annotate(...)``.
+    Disabled cost is one contextvar read, one :func:`recording` check and a
+    shared ``nullcontext``; ``sp`` is ``None`` when disabled, so
+    conditional annotations read ``if sp is not None: sp.annotate(...)``.
     """
     tracer = _ACTIVE.get()
-    if tracer is None:
-        return _NULL
-    return tracer.span(name, cat=cat, mode=mode, **args)
+    if tracer is not None:
+        return tracer.span(name, cat=cat, mode=mode, **args)
+    if recording():
+        return _Annotation(name, args)
+    return _NULL

@@ -19,7 +19,11 @@ changed, and why it matters for the paper's SMA story:
   the temporal SMA substrate switches modes per *run of ticks*, not per
   request.  Each tick runs under a mode-tagged span
   (``serving.tick.prefill`` / ``serving.tick.decode``) so
-  ``obs.runtime_section`` measures the realized switch count.
+  ``obs.runtime_section`` measures the realized switch count; inside it,
+  ``serving.pack``, ``engine.call``, ``serving.fetch`` and
+  ``serving.sample`` split the tick's host work, and ``serving.admit``
+  covers the admission and planning before it.  Under a JAX profiler
+  session these spans sit on the device trace's clock.
 * **One compile per (phase, bucket)** — both phases run through
   ``sma_jit`` engines; batches are padded to power-of-two row buckets and
   prefill chunks to a fixed width, so the set of abstract signatures is
@@ -54,6 +58,15 @@ from repro.serving.kv_cache import CacheConfig, PagedKVCache
 from repro.serving.scheduler import ModeScheduler, SchedulerConfig, TickPlan
 
 __all__ = ["Request", "ServeEngine"]
+
+#: Counters every engine keeps (present from construction, so a zero is
+#: a reading): real and padding rows per tick phase, the ticks at which
+#: the queue's head stayed queued, by what it waited for, and the rows
+#: left free at the ticks the KV pool blocked it.
+COUNTERS = ("serving.rows.prefill", "serving.rows.decode",
+            "serving.pad_rows.prefill", "serving.pad_rows.decode",
+            "serving.admit_blocked.kv", "serving.admit_blocked.rows",
+            "serving.kv_blocked_rows")
 
 
 @dataclasses.dataclass
@@ -115,6 +128,8 @@ class ServeEngine:
         legacy = SMAOptions(backend=self.rt.backend,
                             interpret=self.rt.interpret or None)
         self.options = legacy.overlay(options).replace(jit=True)
+        for name in COUNTERS:
+            _metrics.inc(name, 0)
         # One engine per phase.  Batches are padded to pow2 row buckets and
         # prefill chunks to scheduler.prefill_chunk, so each phase has one
         # compile per bucket and every later tick is a cache hit.
@@ -200,17 +215,23 @@ class ServeEngine:
         _metrics.inc("serving.admitted")
         return True
 
-    def _admit_from_queue(self) -> None:
+    def _admit_from_queue(self) -> Tuple[int, Optional[str]]:
         """Drain the FIFO head into free rows — every tick, so requests
-        join mid-flight (continuous batching)."""
+        join mid-flight (continuous batching).  Returns how many were
+        admitted, and what the head that stayed queued waits for:
+        ``"rows"`` (every row busy), ``"kv"`` (the pool cannot reserve its
+        budget) or None (the queue drained)."""
+        admitted = 0
         while self.queue:
             head = self.queue[0]
             if self._validate(head):
                 self.queue.pop(0)
                 continue
             if not self.try_admit(head):
-                return
+                return admitted, "kv" if self.free_rows() else "rows"
             self.queue.pop(0)
+            admitted += 1
+        return admitted, None
 
     def admit_sync(self, req: Request) -> bool:
         """Legacy slot-API admission (the deprecated ``Server.admit``):
@@ -253,12 +274,25 @@ class ServeEngine:
 
         Returns ``{rid: token}`` for tokens emitted this tick.
         """
-        self._admit_from_queue()
-        prefill_rows = [r.slot for r in self._prefill_reqs()]
-        decode_rows = sorted(r.slot for r in self._decode_reqs())
-        plan = self.sched.plan(prefill_rows, decode_rows)
+        with _obs_trace.span("serving.admit", cat="serve") as sp:
+            admitted, blocked = self._admit_from_queue()
+            prefill_rows = [r.slot for r in self._prefill_reqs()]
+            decode_rows = sorted(r.slot for r in self._decode_reqs())
+            plan = self.sched.plan(prefill_rows, decode_rows)
+            if sp is not None:
+                sp.annotate(admitted=admitted, blocked=blocked or "none")
         if plan.phase == "idle":
             return {}
+        if blocked is not None:
+            _metrics.inc(f"serving.admit_blocked.{blocked}")
+            if blocked == "kv":
+                _metrics.inc("serving.kv_blocked_rows",
+                             len(self.free_rows()))
+                kv = self.kv.stats(self.cache_len)
+                _metrics.observe("serving.kv.positions_in_use",
+                                 kv["positions_in_use"])
+                _metrics.observe("serving.kv.positions_reserved",
+                                 kv["positions_reserved"])
         t0 = time.perf_counter()
         out: Dict[int, int] = {}
         try:
@@ -307,20 +341,27 @@ class ServeEngine:
         if plan.switched:
             _metrics.inc("serving.mode_switches")
         _metrics.inc("serving.ticks")
+        n, bucket = len(plan.rows), self._bucket(len(plan.rows))
         with _obs_trace.span(f"serving.tick.{plan.phase}", cat="serve",
-                             mode=plan.mode, rows=len(plan.rows)):
+                             mode=plan.mode, rows=n, bucket=bucket,
+                             pad=bucket - n, tick=self.sched.ticks):
             if plan.phase == "prefill":
                 return self._prefill_tick(list(plan.rows))
             return self._decode_tick(list(plan.rows))
 
     # ------------------------------------------------------------- internals
-    @staticmethod
-    def _bucket(n: int) -> int:
-        return 1 << max(0, n - 1).bit_length() if n > 1 else 1
+    def _bucket(self, n: int) -> int:
+        """The power-of-two row bucket a tick of ``n`` rows runs at."""
+        b = 1 << max(0, n - 1).bit_length() if n > 1 else 1
+        return min(self.max_batch, b)
 
-    def _padded_rows(self, rows: List[int]) -> Tuple[np.ndarray, int]:
-        bucket = min(self.max_batch, self._bucket(len(rows)))
-        pad = bucket - len(rows)
+    def _padded_rows(self, phase: str,
+                     rows: List[int]) -> Tuple[np.ndarray, int]:
+        """The tick's rows padded to its bucket with copies of the first
+        (full work, results dropped); counted per phase."""
+        pad = self._bucket(len(rows)) - len(rows)
+        _metrics.inc(f"serving.rows.{phase}", len(rows))
+        _metrics.inc(f"serving.pad_rows.{phase}", pad)
         return np.asarray(rows + [rows[0]] * pad, np.int32), pad
 
     def _gather(self, rows_padded: np.ndarray) -> Tuple[Any, ...]:
@@ -386,43 +427,52 @@ class ServeEngine:
         self.active.pop(req.rid, None)
         self.kv.release(req.slot)
 
+    def _fetch(self, logits: jax.Array, n: int) -> np.ndarray:
+        """The real rows' logits on the host: the tick's wait on the
+        device."""
+        with _obs_trace.span("serving.fetch", cat="serve"):
+            return np.asarray(logits[:n], np.float32)
+
     def _prefill_tick(self, rows: List[int]) -> Dict[int, int]:
         by_row = self._by_row()
         reqs = [by_row[r] for r in rows]
         c = self.sched.config.prefill_chunk
-        rows_padded, pad = self._padded_rows(rows)
-        bucket = len(rows_padded)
-        toks = np.zeros((bucket, c), np.int32)
-        n_tok = np.zeros((bucket,), np.int32)
-        chunk_n: List[int] = []
-        for i, req in enumerate(reqs):
-            m = min(c, len(req.prompt) - req.prefilled)
-            toks[i, :m] = req.prompt[req.prefilled:req.prefilled + m]
-            n_tok[i] = m
-            chunk_n.append(m)
-        bt = np.vstack([self.kv.table_rows(rows),
-                        self.kv.sentinel_rows(pad)])
-        cl = np.concatenate([self.cache_len[rows],
-                             np.zeros((pad,), np.int32)])
-        logits, new_state, _ = self.engines["prefill"](
-            self.params, self._gather(rows_padded), jnp.asarray(bt),
-            jnp.asarray(cl), jnp.asarray(n_tok), self._batch_of(toks))
-        np_logits = np.asarray(logits[:len(rows)], np.float32)
-        good_idx = [i for i in range(len(rows))
-                    if np.isfinite(np_logits[i]).all()]
-        bad = [reqs[i] for i in range(len(rows)) if i not in good_idx]
-        self._scatter(new_state, rows, good_idx)
-        out: Dict[int, int] = {}
-        for i in good_idx:
-            req = reqs[i]
-            self.cache_len[req.slot] += chunk_n[i]
-            req.prefilled += chunk_n[i]
-            if req.prefilled >= len(req.prompt) and req.emit_first:
-                tok = self._sample(np_logits[i])
-                self._emit(req, tok)
-                out[req.rid] = tok
-        for req in bad:
-            self._charge_retry(req, "non-finite logits")
+        with _obs_trace.span("serving.pack", cat="serve"):
+            rows_padded, pad = self._padded_rows("prefill", rows)
+            bucket = len(rows_padded)
+            toks = np.zeros((bucket, c), np.int32)
+            n_tok = np.zeros((bucket,), np.int32)
+            chunk_n: List[int] = []
+            for i, req in enumerate(reqs):
+                m = min(c, len(req.prompt) - req.prefilled)
+                toks[i, :m] = req.prompt[req.prefilled:req.prefilled + m]
+                n_tok[i] = m
+                chunk_n.append(m)
+            bt = np.vstack([self.kv.table_rows(rows),
+                            self.kv.sentinel_rows(pad)])
+            cl = np.concatenate([self.cache_len[rows],
+                                 np.zeros((pad,), np.int32)])
+            args = (self.params, self._gather(rows_padded), jnp.asarray(bt),
+                    jnp.asarray(cl), jnp.asarray(n_tok),
+                    self._batch_of(toks))
+        logits, new_state, _ = self.engines["prefill"](*args)
+        np_logits = self._fetch(logits, len(rows))
+        with _obs_trace.span("serving.sample", cat="serve"):
+            good_idx = [i for i in range(len(rows))
+                        if np.isfinite(np_logits[i]).all()]
+            bad = [reqs[i] for i in range(len(rows)) if i not in good_idx]
+            self._scatter(new_state, rows, good_idx)
+            out: Dict[int, int] = {}
+            for i in good_idx:
+                req = reqs[i]
+                self.cache_len[req.slot] += chunk_n[i]
+                req.prefilled += chunk_n[i]
+                if req.prefilled >= len(req.prompt) and req.emit_first:
+                    tok = self._sample(np_logits[i])
+                    self._emit(req, tok)
+                    out[req.rid] = tok
+            for req in bad:
+                self._charge_retry(req, "non-finite logits")
         return out
 
     def _decode_tick(self, rows: List[int]) -> Dict[int, int]:
@@ -439,37 +489,39 @@ class ServeEngine:
         if not rows:
             return {}
         reqs = [by_row[r] for r in rows]
-        rows_padded, pad = self._padded_rows(rows)
-        bucket = len(rows_padded)
-        toks = np.zeros((bucket, 1), np.int32)
-        for i, req in enumerate(reqs):
-            toks[i, 0] = (req.out_tokens[-1] if req.out_tokens
-                          else int(req.prompt[-1]))
-        bt = np.vstack([self.kv.table_rows(rows),
-                        self.kv.sentinel_rows(pad)])
-        cl = np.concatenate([self.cache_len[rows],
-                             np.zeros((pad,), np.int32)])
-        logits, new_state, _ = self.engines["decode"](
-            self.params, self._gather(rows_padded), jnp.asarray(bt),
-            jnp.asarray(cl), self._batch_of(toks))
-        np_logits = np.asarray(logits[:len(rows)], np.float32)
-        # Containment: rows whose logits went non-finite are poisoned —
-        # only healthy rows advance (state scatter + cache_len), and the
-        # poisoned requests are charged a bounded retry.  Healthy rows are
-        # never held back by a sick neighbour.
-        good_idx = [i for i in range(len(rows))
-                    if np.isfinite(np_logits[i]).all()]
-        bad = [reqs[i] for i in range(len(rows)) if i not in good_idx]
-        self._scatter(new_state, rows, good_idx)
-        out: Dict[int, int] = {}
-        for i in good_idx:
-            req = reqs[i]
-            self.cache_len[req.slot] += 1
-            tok = self._sample(np_logits[i])
-            self._emit(req, tok)
-            out[req.rid] = tok
-        for req in bad:
-            self._charge_retry(req, "non-finite logits")
+        with _obs_trace.span("serving.pack", cat="serve"):
+            rows_padded, pad = self._padded_rows("decode", rows)
+            bucket = len(rows_padded)
+            toks = np.zeros((bucket, 1), np.int32)
+            for i, req in enumerate(reqs):
+                toks[i, 0] = (req.out_tokens[-1] if req.out_tokens
+                              else int(req.prompt[-1]))
+            bt = np.vstack([self.kv.table_rows(rows),
+                            self.kv.sentinel_rows(pad)])
+            cl = np.concatenate([self.cache_len[rows],
+                                 np.zeros((pad,), np.int32)])
+            args = (self.params, self._gather(rows_padded), jnp.asarray(bt),
+                    jnp.asarray(cl), self._batch_of(toks))
+        logits, new_state, _ = self.engines["decode"](*args)
+        np_logits = self._fetch(logits, len(rows))
+        with _obs_trace.span("serving.sample", cat="serve"):
+            # Containment: rows whose logits went non-finite are poisoned
+            # — only healthy rows advance (state scatter + cache_len), and
+            # the poisoned requests are charged a bounded retry.  Healthy
+            # rows are never held back by a sick neighbour.
+            good_idx = [i for i in range(len(rows))
+                        if np.isfinite(np_logits[i]).all()]
+            bad = [reqs[i] for i in range(len(rows)) if i not in good_idx]
+            self._scatter(new_state, rows, good_idx)
+            out: Dict[int, int] = {}
+            for i in good_idx:
+                req = reqs[i]
+                self.cache_len[req.slot] += 1
+                tok = self._sample(np_logits[i])
+                self._emit(req, tok)
+                out[req.rid] = tok
+            for req in bad:
+                self._charge_retry(req, "non-finite logits")
         return out
 
     # -------------------------------------------------------- failure paths
@@ -576,7 +628,8 @@ class ServeEngine:
         eng = {name: {"hits": e.stats.hits, "misses": e.stats.misses,
                       "compile_time_s": e.stats.compile_time_s}
                for name, e in self.engines.items()}
-        return {"kv": self.kv.stats(), "scheduler": self.sched.stats(),
+        return {"kv": self.kv.stats(self.cache_len),
+                "scheduler": self.sched.stats(),
                 "engines": eng,
                 "requests": {"queued": len(self.queue),
                              "active": len(self.active),

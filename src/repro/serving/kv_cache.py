@@ -125,6 +125,7 @@ class PagedKVCache:
         self._tables = np.full(
             (max_batch, config.max_blocks_per_req), self.sentinel, np.int32)
         self._blocks: Dict[int, List[int]] = {}
+        self._budgets: Dict[int, int] = {}   # row -> reserved positions
 
     # -------------------------------------------------------------- admission
     def admission_error(self, prompt_len: int,
@@ -166,6 +167,7 @@ class PagedKVCache:
         if blocks is None:
             return False
         self._blocks[row] = blocks
+        self._budgets[row] = budget
         self._tables[row, :] = self.sentinel
         self._tables[row, :len(blocks)] = blocks
         return True
@@ -175,6 +177,7 @@ class PagedKVCache:
         """Free ``row``'s blocks (no-op for an empty row); returns how many
         blocks were returned to the pool."""
         blocks = self._blocks.pop(row, None)
+        self._budgets.pop(row, None)
         self._tables[row, :] = self.sentinel
         if not blocks:
             return 0
@@ -199,22 +202,32 @@ class PagedKVCache:
         return np.full((n, self.config.max_blocks_per_req), self.sentinel,
                        np.int32)
 
-    def stats(self) -> Dict[str, float]:
-        """Occupancy/fragmentation counters (fed to ``obs.metrics`` and the
-        allocator tests): internal fragmentation is the tail waste of
-        partially-resident budgets — allocated positions that can never be
-        used because budgets are not block-multiples."""
+    def stats(self, lengths: Optional[np.ndarray] = None
+              ) -> Dict[str, float]:
+        """Occupancy counters (the allocator tests read them; the serving
+        engine feeds ``serving.kv.positions_*`` to ``obs.metrics`` at each
+        tick whose admission the pool blocks).
+
+        ``resident_positions`` are the positions the allocated blocks hold,
+        ``positions_reserved`` the budgets (prompt + max_new) admission
+        reserved, and — given each row's current length (``lengths``,
+        indexed by row) — ``positions_in_use`` those written so far: the
+        gap between the last two is what budget reservation costs the
+        pool."""
         used = self.allocator.num_used
         cfg = self.config
-        waste = sum(len(b) * cfg.block_size for b in self._blocks.values())
-        # subtract each row's actual budgeted positions lazily: callers that
-        # need exact per-row waste pass budgets; here we report pool-level
-        # occupancy only.
-        return {
+        resident = sum(len(b) * cfg.block_size
+                       for b in self._blocks.values())
+        out = {
             "num_blocks": float(cfg.num_blocks),
             "blocks_used": float(used),
             "blocks_free": float(self.allocator.num_free),
             "utilization": used / cfg.num_blocks,
             "resident_requests": float(len(self._blocks)),
-            "resident_positions": float(waste),
+            "resident_positions": float(resident),
+            "positions_reserved": float(sum(self._budgets.values())),
         }
+        if lengths is not None:
+            out["positions_in_use"] = float(
+                sum(int(lengths[row]) for row in self._blocks))
+        return out

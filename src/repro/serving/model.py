@@ -149,8 +149,9 @@ def _paged_attn(bparams: dict, x: jax.Array, bstate: dict,
     b, c, _ = x.shape
     h = rmsnorm_apply(bparams["norm1"], x)
     q, k, v = attention._project_qkv(bparams["mixer"], h, cfg, q_pos)
-    new_k = _pool_write(bstate["k"], block_table, q_pos, k, valid)
-    new_v = _pool_write(bstate["v"], block_table, q_pos, v, valid)
+    with jax.named_scope("kv_write"):
+        new_k = _pool_write(bstate["k"], block_table, q_pos, k, valid)
+        new_v = _pool_write(bstate["v"], block_table, q_pos, v, valid)
     out = kops.paged_decode_attention(
         q, new_k, new_v, block_table, q_pos, kv_len.astype(jnp.int32),
         window=window)
@@ -261,7 +262,8 @@ def paged_decode_step(params: dict, state: Tuple[Any, ...],
     writes; batch tokens (B, 1) (or embeds).  Returns (logits (B, Vpad),
     new_state, cache_len + 1).
     """
-    x = _embed(params, cfg, batch)                      # (B, 1, D)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, batch)                  # (B, 1, D)
 
     def group_body(x, xs):
         gparams, gstate = xs
@@ -272,9 +274,12 @@ def paged_decode_step(params: dict, state: Tuple[Any, ...],
             new_gstate.append(ns)
         return x, tuple(new_gstate)
 
-    x, new_state = jax.lax.scan(group_body, x, (params["blocks"], state),
-                                unroll=rt.scan_unroll)
-    logits = _head(params, x)
+    with jax.named_scope("layers"):
+        x, new_state = jax.lax.scan(group_body, x,
+                                    (params["blocks"], state),
+                                    unroll=rt.scan_unroll)
+    with jax.named_scope("head"):
+        logits = _head(params, x)
     return logits[:, 0], new_state, cache_len + 1
 
 
@@ -290,7 +295,8 @@ def paged_prefill_step(params: dict, state: Tuple[Any, ...],
     each row's last valid position (B, Vpad), new_state,
     cache_len + n_tokens).
     """
-    x = _embed(params, cfg, batch)                      # (B, C, D)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, batch)                  # (B, C, D)
     b, c, _ = x.shape
     q_pos = cache_len[:, None] + jnp.arange(c)[None, :]       # (B, C)
     valid = jnp.arange(c)[None, :] < n_tokens[:, None]        # (B, C)
@@ -306,9 +312,12 @@ def paged_prefill_step(params: dict, state: Tuple[Any, ...],
             new_gstate.append(ns)
         return x, tuple(new_gstate)
 
-    x, new_state = jax.lax.scan(group_body, x, (params["blocks"], state),
-                                unroll=rt.scan_unroll)
-    last = jnp.clip(n_tokens - 1, 0, c - 1)                   # (B,)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    logits = _head(params, x_last)                            # (B, 1, Vpad)
+    with jax.named_scope("layers"):
+        x, new_state = jax.lax.scan(group_body, x,
+                                    (params["blocks"], state),
+                                    unroll=rt.scan_unroll)
+    with jax.named_scope("head"):
+        last = jnp.clip(n_tokens - 1, 0, c - 1)               # (B,)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        logits = _head(params, x_last)                        # (B, 1, Vpad)
     return logits[:, 0], new_state, cache_len + n_tokens

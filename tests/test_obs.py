@@ -96,7 +96,8 @@ class TestTracing:
             assert server.admit(req)
             server.tick()
         names = {e["name"] for e in prof.events}
-        assert {"serve.admit", "serve.warmup", "serve.tick"} <= names
+        assert {"serve.admit", "serve.warmup",
+                "serving.tick.decode"} <= names
 
 
 # ===========================================================================
