@@ -80,22 +80,25 @@ def _ops(interpret: bool):
         return _kernel(q, k_cache, v_cache, cache_len,
                        scale=scale, block_s=block_s, interpret=interpret)
 
-    def paged_decode_attention(q, k_pool, v_pool, block_table, q_pos,
-                               kv_len, *, window=None, scale=None,
+    def paged_decode_attention(q, k_pool, v_pool, layer, block_table,
+                               q_pos, kv_len, *, window=None, scale=None,
                                block_s=512):
         # Constraints route chunked (C>1) and windowed sites to xla, so
-        # here q is (B, 1, Hq, D) and the site is plain decode: gather the
-        # request's pages into a contiguous per-request cache, then run the
-        # existing decode kernel (its cache_len block-skip becomes the
-        # page-tail skip).
+        # here q is (B, 1, Hq, D) and the site is plain decode.  The pools
+        # are the stacked token-major (L, NB, BS, Hkv*D) arrays: one
+        # indexed gather takes the request's pages of ``layer``, which are
+        # re-laid out as a contiguous (B, Hkv, S, D) cache for the existing
+        # decode kernel (its cache_len block-skip becomes the page-tail
+        # skip).
         del q_pos, window
         from repro.kernels.decode_attention import \
             decode_attention as _kernel
-        nb, hkv, bs, hd = k_pool.shape
-        b = q.shape[0]
+        _, nb, _, width = k_pool.shape
+        b, _, _, hd = q.shape
+        hkv = width // hd
         bt = jnp.clip(block_table, 0, nb - 1)
-        k = k_pool[bt].transpose(0, 2, 1, 3, 4).reshape(b, hkv, -1, hd)
-        v = v_pool[bt].transpose(0, 2, 1, 3, 4).reshape(b, hkv, -1, hd)
+        k = k_pool[layer, bt].reshape(b, -1, hkv, hd).swapaxes(1, 2)
+        v = v_pool[layer, bt].reshape(b, -1, hkv, hd).swapaxes(1, 2)
         # Temporary: XLA names the kernel's instruction after the scope
         # that directly encloses it.  Inside the serving layer scan that
         # was the body's ``closed_call`` before sites were scoped, and the

@@ -231,12 +231,13 @@ def _op_decode_attention(q, k_cache, v_cache, cache_len, *, scale=None,
                                      scale=scale)
 
 
-def _op_paged_decode_attention(q, k_pool, v_pool, block_table, q_pos,
-                               kv_len, *, window=None, scale=None,
+def _op_paged_decode_attention(q, k_pool, v_pool, layer, block_table,
+                               q_pos, kv_len, *, window=None, scale=None,
                                block_s=512):
     del block_s  # kernel-backend tiling knob
-    return _ref.paged_attention_ref(q, k_pool, v_pool, block_table, q_pos,
-                                    kv_len, window=window, scale=scale)
+    return _ref.paged_attention_ref(q, k_pool, v_pool, layer, block_table,
+                                    q_pos, kv_len, window=window,
+                                    scale=scale)
 
 
 def _op_rglru_scan(a, u, h0=None, *, block_s=256, block_d=256):

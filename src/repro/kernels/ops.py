@@ -302,8 +302,9 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, block_table: jax.Array,
-                           q_pos: jax.Array, kv_len: jax.Array, *,
+                           v_pool: jax.Array, layer: jax.Array,
+                           block_table: jax.Array, q_pos: jax.Array,
+                           kv_len: jax.Array, *,
                            window: Optional[int] = None,
                            scale: Optional[float] = None,
                            backend: Any = None,
@@ -312,15 +313,20 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     """Block-table GQA attention over a paged KV pool (serving).
 
     q (B, C, Hq, D) — C query tokens per request (C=1: decode; C>1: a
-    chunked-prefill tile); k/v_pool (NB, Hkv, BS, D) — the global block
-    pool; block_table (B, MB) int32 per-request page ids (entries >= NB
-    are unallocated); q_pos (B, C) absolute query positions; kv_len (B,)
-    valid lengths including this chunk.  Returns (B, C, Hq, D).
+    chunked-prefill tile); k/v_pool (L, NB, BS, Hkv*D) — the stacked
+    token-major block pools of every layer, as the serving layer scan
+    carries them; layer () int32 — the layer to read; block_table (B, MB)
+    int32 per-request page ids (entries >= NB are unallocated); q_pos
+    (B, C) absolute query positions; kv_len (B,) valid lengths including
+    this chunk.  Returns (B, C, Hq, D).
 
-    The kernel backends take single-token non-windowed sites (page gather
-    + the existing decode kernel, so block-level cache-tail skipping is
-    preserved); chunked and windowed sites resolve down the ladder to the
-    grouped-head SIMD path (:func:`repro.kernels.ref.paged_attention_ref`).
+    Every backend gathers a request's pages with one indexed gather,
+    ``pool[layer, block_table]``; no layer's pool is sliced out whole.  The
+    kernel backends take single-token non-windowed sites (the gathered
+    pages re-laid out as (B, Hkv, S, D) for the existing decode kernel, so
+    block-level cache-tail skipping is preserved); chunked and windowed
+    sites resolve down the ladder to the grouped-head SIMD path
+    (:func:`repro.kernels.ref.paged_attention_ref`).
     """
     kn = _knobs(backend=backend, interpret=interpret)
     # The site is the float operands only: the int32 block table would
@@ -329,7 +335,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         "paged_decode_attention", (q, k_pool, v_pool),
         kn["backend"], kn["interpret"],
         lambda be: lambda: be.op("paged_decode_attention")(
-            q, k_pool, v_pool, block_table, q_pos, kv_len,
+            q, k_pool, v_pool, layer, block_table, q_pos, kv_len,
             window=window, scale=scale, block_s=block_s),
         attrs={"blocks": [block_s], "chunk": int(q.shape[1]),
                "window": window},

@@ -129,38 +129,41 @@ def decode_attention_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 
 def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                        block_table: jax.Array, q_pos: jax.Array,
-                        kv_len: jax.Array, *,
+                        layer: jax.Array, block_table: jax.Array,
+                        q_pos: jax.Array, kv_len: jax.Array, *,
                         window: Optional[int] = None,
                         scale: Optional[float] = None) -> jax.Array:
     """Block-table attention over a paged KV pool (decode + chunked prefill).
 
     q (B, C, Hq, D) — C query tokens per request (C=1 is plain decode);
-    k/v_pool (NB, Hkv, BS, D) — the global block pool (no batch axis);
-    block_table (B, MB) int32 — per-request block ids, entries >= NB are
-    unallocated padding; q_pos (B, C) — absolute positions of the query
-    tokens; kv_len (B,) — valid cache length *including* this chunk.
-    Returns (B, C, Hq, D).
+    k/v_pool (L, NB, BS, Hkv*D) — the stacked token-major block pools of
+    all layers (no batch axis), read at ``layer`` (a () int32); block_table
+    (B, MB) int32 — per-request block ids, entries >= NB are unallocated
+    padding; q_pos (B, C) — absolute positions of the query tokens; kv_len
+    (B,) — valid cache length *including* this chunk.  Returns
+    (B, C, Hq, D).
 
-    Grouped-head einsums like :func:`decode_attention_ref` (KV is never
-    expanded to Hq).  Masking uses -1e30 rather than -inf so fully-masked
-    rows (batch-padding rows with kv_len=0) stay finite instead of NaN.
+    Each request's pages come out of the pool in one indexed gather,
+    ``pool[layer, block_table]``, already token-major (B, MB*BS, Hkv, D):
+    no layer's pool is sliced out whole.  Grouped-head einsums like
+    :func:`decode_attention_ref` (KV is never expanded to Hq).  Masking
+    uses -1e30 rather than -inf so fully-masked rows (batch-padding rows
+    with kv_len=0) stay finite instead of NaN.
     """
     orig_dtype = q.dtype
     b, c, hq, head_dim = q.shape
-    nb, hkv, bs, _ = k_pool.shape
+    _, nb, bs, width = k_pool.shape
     mb = block_table.shape[1]
+    hkv = width // head_dim
     g = hq // hkv
     scale = scale if scale is not None else head_dim ** -0.5
     # Gather each request's pages; sentinel entries clamp into a real block
     # whose positions the validity mask below excludes.
     bt = jnp.clip(block_table, 0, nb - 1)
-    k = k_pool[bt].transpose(0, 2, 1, 3, 4).reshape(b, hkv, mb * bs,
-                                                    head_dim)
-    v = v_pool[bt].transpose(0, 2, 1, 3, 4).reshape(b, hkv, mb * bs,
-                                                    head_dim)
+    k = k_pool[layer, bt].reshape(b, mb * bs, hkv, head_dim)
+    v = v_pool[layer, bt].reshape(b, mb * bs, hkv, head_dim)
     q5 = q.reshape(b, c, hkv, g, head_dim).astype(jnp.float32) * scale
-    logits = jnp.einsum("bchgd,bhkd->bchgk", q5, k.astype(jnp.float32))
+    logits = jnp.einsum("bchgd,bkhd->bchgk", q5, k.astype(jnp.float32))
     k_pos = jnp.arange(mb * bs)
     mask = k_pos[None, None, :] < kv_len[:, None, None]        # valid
     mask &= k_pos[None, None, :] <= q_pos[:, :, None]          # causal
@@ -168,7 +171,7 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         mask &= k_pos[None, None, :] > q_pos[:, :, None] - window
     logits = jnp.where(mask[:, :, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bchgk,bhkd->bchgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bchgk,bkhd->bchgd", probs, v.astype(jnp.float32))
     return out.reshape(b, c, hq, head_dim).astype(orig_dtype)
 
 

@@ -1,8 +1,9 @@
 """Paged KV cache: fixed-size blocks, free-list allocator, block tables.
 
 The serving engine's KV memory is one global pool of ``num_blocks`` blocks
-of ``block_size`` token positions each (per attention layer, per KV head —
-the device arrays live in the engine's state pytree; this module owns the
+of ``block_size`` token positions each (per attention layer, a position
+holding every KV head — the device arrays live in the engine's state
+pytree, see :func:`repro.serving.model.init_state`; this module owns the
 *bookkeeping*: which request holds which blocks).  vLLM-style paging:
 
 * Admission allocates a request's whole budget up front
@@ -10,8 +11,9 @@ the device arrays live in the engine's state pytree; this module owns the
   enters the batch can never OOM mid-decode — admission is the only
   failure point, and it reuses the resilience rejection path (a clear
   ``failed`` status, never a silent overflow).
-* Appending a token is copy-free: the engine scatters the new K/V row into
-  ``pool[block_table[row, pos // bs], :, pos % bs]`` — no per-step
+* Appending a token is copy-free: the engine scatters the new K/V row
+  (all KV heads, ``Hkv*D`` wide) into
+  ``pool[layer, block_table[row, pos // bs], pos % bs]`` — no per-step
   reshuffle of earlier positions, regardless of how ragged the batch is.
 * Release (completion or eviction) returns the blocks to the free list;
   a freed block is safe to reuse immediately because readers mask on

@@ -3,11 +3,19 @@
 The serving twins of :func:`repro.models.lm.decode_step` / ``prefill``:
 same block structure (stacked groups under ``lax.scan``, per-pattern-position
 state entries), but attention layers keep their KV in the *global* paged
-pool — state entries for ``attn``/``local`` positions are
-``{"k","v"}: (num_groups, num_blocks, Hkv, block_size, head_dim)`` with NO
-batch axis; which rows of the pool belong to which request is carried by the
+pool.  State entries for ``attn``/``local`` positions are
+``{"k","v"}: (num_groups, num_blocks, block_size, num_kv_heads * head_dim)``,
+token-major (one row of ``Hkv*D`` per cached position) with NO batch axis;
+which blocks of the pool belong to which request is carried by the
 ``block_table`` argument.  Recurrent positions (RG-LRU / mLSTM / sLSTM) keep
 their dense per-row state exactly as in ``lm.init_state``.
+
+Data flow through the layer scan: the pools ride the scan's *carry* and are
+written and read at the group's layer index (an ``xs`` of
+``arange(num_groups)``); no layer's pool is sliced out of the stack or
+stacked back.  The stacked block params and the recurrent states are the
+scan's ``xs``/``ys``.  With the state donated, each step updates the pools
+in place: the only pool-sized instructions left are the writes' scatters.
 
 Two entry points, one per serving phase (and per ``sma_jit`` cache family):
 
@@ -20,10 +28,11 @@ Two entry points, one per serving phase (and per ``sma_jit`` cache family):
   per-token, and the returned logits are taken at each row's last *valid*
   position.
 
-Pool writes are copy-free scatters: position ``p`` of a row lands at
-``pool[table[row, p // bs], :, p % bs]``; out-of-budget or padding writes
-carry the sentinel block id (== num_blocks) and drop (``mode="drop"`` —
-note jnp would *wrap* a -1, so the sentinel is one-past-the-end, never -1).
+Pool writes are copy-free scatters: position ``p`` of a row in group ``l``
+lands at ``pool[l, table[row, p // bs], p % bs]``; out-of-budget or padding
+writes carry the sentinel block id (== num_blocks) and drop
+(``mode="drop"`` — note jnp would *wrap* a -1, so the sentinel is
+one-past-the-end, never -1).
 """
 from __future__ import annotations
 
@@ -44,12 +53,16 @@ __all__ = ["init_state", "paged_decode_step", "paged_prefill_step",
 
 def init_state(cfg: ModelConfig, max_batch: int, cache: CacheConfig,
                dtype=None) -> Tuple[Any, ...]:
-    """Serving state pytree: paged pools for attention positions, dense
-    per-row recurrent states (as in ``lm.init_state``) otherwise."""
+    """Serving state pytree, one entry per pattern position.
+
+    ``attn``/``local`` positions hold ``{"k", "v"}`` token-major paged
+    pools of shape ``(num_groups, num_blocks, block_size,
+    num_kv_heads * head_dim)``; the layer scan carries them whole and
+    indexes them at the group's layer.  Recurrent positions hold dense
+    per-row states (as in ``lm.init_state``), stacked over groups."""
     dtype = dtype or cfg.activation_dtype
-    hd = cfg.resolved_head_dim
-    pool_shape = (cfg.num_groups, cache.num_blocks, cfg.num_kv_heads,
-                  cache.block_size, hd)
+    pool_shape = (cfg.num_groups, cache.num_blocks, cache.block_size,
+                  cfg.num_kv_heads * cfg.resolved_head_dim)
     state = []
     for btype in cfg.block_pattern:
         if btype in ("attn", "local"):
@@ -73,8 +86,9 @@ def init_state(cfg: ModelConfig, max_batch: int, cache: CacheConfig,
 
 
 def pooled_positions(cfg: ModelConfig) -> Tuple[int, ...]:
-    """Pattern positions whose state entry is a paged pool (no batch axis).
-    The engine uses this to know which entries to row-gather/scatter."""
+    """Pattern positions whose state entry is a paged pool (no batch axis),
+    decided by the block type.  The layer scan carries these entries; the
+    engine passes them through its row gather/scatter whole."""
     return tuple(p for p, bt in enumerate(cfg.block_pattern)
                  if bt in ("attn", "local"))
 
@@ -99,29 +113,28 @@ def _embed(params: dict, cfg: ModelConfig,
         batch["tokens"]]
 
 
-def _pool_write(pool: jax.Array, block_table: jax.Array, pos: jax.Array,
-                val: jax.Array,
+def _pool_write(pool: jax.Array, layer: jax.Array, block_table: jax.Array,
+                pos: jax.Array, val: jax.Array,
                 valid: Optional[jax.Array] = None) -> jax.Array:
-    """Scatter per-position K/V rows into the paged pool.
+    """Scatter a chunk's K or V into the stacked pool, in place.
 
-    pool (NB, Hkv, BS, D); block_table (B, MB); pos (B,) or (B, C) absolute
-    positions; val (B, [C,] Hkv, D).  ``valid`` (same shape as pos) masks
-    writes by routing them to the sentinel block (dropped).
+    pool (L, NB, BS, Hkv*D), the scan's carried pool; layer () int32, the
+    group being run; block_table (B, MB); pos (B, C) absolute positions;
+    val (B, C, Hkv, D).  Each token writes one row of ``Hkv*D`` at
+    ``pool[layer, block, pos % BS]``.  ``valid`` (B, C) masks writes by
+    routing them to the sentinel block (dropped).
     """
-    nb, _, bs, _ = pool.shape
+    _, nb, bs, _ = pool.shape
     mb = block_table.shape[1]
-    idx = jnp.clip(pos // bs, 0, mb - 1)
-    if pos.ndim == 1:
-        blk = block_table[jnp.arange(pos.shape[0]), idx]
-    else:
-        blk = jnp.take_along_axis(block_table, idx, axis=1)
+    blk = jnp.take_along_axis(block_table,
+                              jnp.clip(pos // bs, 0, mb - 1), axis=1)
     # Positions past the table (can't happen for budget-allocated rows;
     # CAN happen for padding rows) and masked positions write nowhere.
     blk = jnp.where(pos // bs < mb, blk, nb)
     if valid is not None:
         blk = jnp.where(valid, blk, nb)
-    return pool.at[blk, :, pos % bs].set(val.astype(pool.dtype),
-                                         mode="drop")
+    rows = val.reshape(val.shape[:-2] + (-1,)).astype(pool.dtype)
+    return pool.at[layer, blk, pos % bs].set(rows, mode="drop")
 
 
 def _attn_ffn(bparams: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -135,26 +148,27 @@ def _attn_ffn(bparams: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def _paged_attn(bparams: dict, x: jax.Array, bstate: dict,
-                block_table: jax.Array, q_pos: jax.Array,
-                kv_len: jax.Array, cfg: ModelConfig, rt: Runtime, *,
+                layer: jax.Array, block_table: jax.Array, q_pos: jax.Array,
+                kv_len: jax.Array, cfg: ModelConfig, *,
                 window: Optional[int],
                 valid: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, dict]:
     """Attention over the paged pool for a (B, C, D) chunk (C=1: decode).
 
-    Writes the chunk's K/V into the pool (masked writes drop), then runs
-    the block-table attention op.  Returns (residual y (B, C, D), new pool
-    entry)."""
-    del rt
+    Writes the chunk's K/V into the stacked pools at ``layer`` (masked
+    writes drop), then runs the block-table attention op on the same
+    layer.  Returns (residual y (B, C, D), the updated stacked pools)."""
     b, c, _ = x.shape
     h = rmsnorm_apply(bparams["norm1"], x)
     q, k, v = attention._project_qkv(bparams["mixer"], h, cfg, q_pos)
     with jax.named_scope("kv_write"):
-        new_k = _pool_write(bstate["k"], block_table, q_pos, k, valid)
-        new_v = _pool_write(bstate["v"], block_table, q_pos, v, valid)
+        new_k = _pool_write(bstate["k"], layer, block_table, q_pos, k,
+                            valid)
+        new_v = _pool_write(bstate["v"], layer, block_table, q_pos, v,
+                            valid)
     out = kops.paged_decode_attention(
-        q, new_k, new_v, block_table, q_pos, kv_len.astype(jnp.int32),
-        window=window)
+        q, new_k, new_v, layer, block_table, q_pos,
+        kv_len.astype(jnp.int32), window=window)
     y = jnp.einsum("...f,fd->...d", out.reshape(b, c, -1),
                    bparams["mixer"]["wo"].astype(x.dtype))
     return y, {"k": new_k, "v": new_v}
@@ -191,13 +205,14 @@ def _chunk_mixer_scan(decode_fn, bparams: dict, h: jax.Array, bstate,
 
 
 def _prefill_block(bparams: dict, btype: str, x: jax.Array, bstate,
-                   block_table: jax.Array, q_pos: jax.Array,
-                   kv_len: jax.Array, valid: jax.Array, n_tokens: jax.Array,
-                   cfg: ModelConfig, rt: Runtime) -> Tuple[jax.Array, Any]:
+                   layer: jax.Array, block_table: jax.Array,
+                   q_pos: jax.Array, kv_len: jax.Array, valid: jax.Array,
+                   n_tokens: jax.Array, cfg: ModelConfig, rt: Runtime
+                   ) -> Tuple[jax.Array, Any]:
     if btype in ("attn", "local"):
         window = cfg.window if btype == "local" else None
-        y, new_cache = _paged_attn(bparams, x, bstate, block_table, q_pos,
-                                   kv_len, cfg, rt, window=window,
+        y, new_cache = _paged_attn(bparams, x, bstate, layer, block_table,
+                                   q_pos, kv_len, cfg, window=window,
                                    valid=valid)
         return _attn_ffn(bparams, x + y, cfg), new_cache
     h = rmsnorm_apply(bparams["norm1"], x)
@@ -219,13 +234,14 @@ def _prefill_block(bparams: dict, btype: str, x: jax.Array, bstate,
 
 
 def _decode_block(bparams: dict, btype: str, x: jax.Array, bstate,
-                  block_table: jax.Array, cache_len: jax.Array,
-                  cfg: ModelConfig, rt: Runtime) -> Tuple[jax.Array, Any]:
+                  layer: jax.Array, block_table: jax.Array,
+                  cache_len: jax.Array, cfg: ModelConfig, rt: Runtime
+                  ) -> Tuple[jax.Array, Any]:
     if btype in ("attn", "local"):
         window = cfg.window if btype == "local" else None
-        y, new_cache = _paged_attn(bparams, x, bstate, block_table,
+        y, new_cache = _paged_attn(bparams, x, bstate, layer, block_table,
                                    cache_len[:, None], cache_len + 1,
-                                   cfg, rt, window=window)
+                                   cfg, window=window)
         return _attn_ffn(bparams, x + y, cfg), new_cache
     h = rmsnorm_apply(bparams["norm1"], x)
     if btype == "rglru":
@@ -243,6 +259,38 @@ def _decode_block(bparams: dict, btype: str, x: jax.Array, bstate,
                                              cfg, rt)
         return x + y, ns
     raise ValueError(btype)
+
+
+def _layer_scan(block, x: jax.Array, params: dict, state: Tuple[Any, ...],
+                cfg: ModelConfig, rt: Runtime
+                ) -> Tuple[jax.Array, Tuple[Any, ...]]:
+    """Run ``block(bparams, btype, x, bstate, layer)`` over every group.
+
+    The pooled positions' stacked pools ride the scan's carry beside
+    ``x`` and are written and read at ``layer`` in place; the stacked
+    block params, the recurrent positions' per-group states and the layer
+    index are the scan's ``xs``, the new recurrent states its ``ys``.
+    Returns (x, the new state tuple)."""
+    pools = {p: state[p] for p in pooled_positions(cfg)}
+    per_layer = tuple(None if p in pools else s for p, s in enumerate(state))
+
+    def group_body(carry, xs):
+        x, pools = carry
+        gparams, gstate, layer = xs
+        pools, gstate = dict(pools), list(gstate)
+        for p, btype in enumerate(cfg.block_pattern):
+            entries = pools if p in pools else gstate
+            x, entries[p] = block(gparams[p], btype, x, entries[p], layer)
+        return (x, pools), tuple(gstate)
+
+    with jax.named_scope("layers"):
+        (x, pools), per_layer = jax.lax.scan(
+            group_body, (x, pools),
+            (params["blocks"], per_layer,
+             jnp.arange(cfg.num_groups, dtype=jnp.int32)),
+            unroll=rt.scan_unroll)
+    return x, tuple(pools[p] if p in pools else s
+                    for p, s in enumerate(per_layer))
 
 
 def _head(params: dict, x: jax.Array) -> jax.Array:
@@ -265,19 +313,11 @@ def paged_decode_step(params: dict, state: Tuple[Any, ...],
     with jax.named_scope("embed"):
         x = _embed(params, cfg, batch)                  # (B, 1, D)
 
-    def group_body(x, xs):
-        gparams, gstate = xs
-        new_gstate = []
-        for p, btype in enumerate(cfg.block_pattern):
-            x, ns = _decode_block(gparams[p], btype, x, gstate[p],
-                                  block_table, cache_len, cfg, rt)
-            new_gstate.append(ns)
-        return x, tuple(new_gstate)
+    def block(bparams, btype, x, bstate, layer):
+        return _decode_block(bparams, btype, x, bstate, layer, block_table,
+                             cache_len, cfg, rt)
 
-    with jax.named_scope("layers"):
-        x, new_state = jax.lax.scan(group_body, x,
-                                    (params["blocks"], state),
-                                    unroll=rt.scan_unroll)
+    x, new_state = _layer_scan(block, x, params, state, cfg, rt)
     with jax.named_scope("head"):
         logits = _head(params, x)
     return logits[:, 0], new_state, cache_len + 1
@@ -302,20 +342,11 @@ def paged_prefill_step(params: dict, state: Tuple[Any, ...],
     valid = jnp.arange(c)[None, :] < n_tokens[:, None]        # (B, C)
     kv_len = cache_len + n_tokens
 
-    def group_body(x, xs):
-        gparams, gstate = xs
-        new_gstate = []
-        for p, btype in enumerate(cfg.block_pattern):
-            x, ns = _prefill_block(gparams[p], btype, x, gstate[p],
-                                   block_table, q_pos, kv_len, valid,
-                                   n_tokens, cfg, rt)
-            new_gstate.append(ns)
-        return x, tuple(new_gstate)
+    def block(bparams, btype, x, bstate, layer):
+        return _prefill_block(bparams, btype, x, bstate, layer, block_table,
+                              q_pos, kv_len, valid, n_tokens, cfg, rt)
 
-    with jax.named_scope("layers"):
-        x, new_state = jax.lax.scan(group_body, x,
-                                    (params["blocks"], state),
-                                    unroll=rt.scan_unroll)
+    x, new_state = _layer_scan(block, x, params, state, cfg, rt)
     with jax.named_scope("head"):
         last = jnp.clip(n_tokens - 1, 0, c - 1)               # (B,)
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)

@@ -134,9 +134,15 @@ class TestPagedAttentionOp:
         out = np.einsum("bchgl,blhd->bchgd", p, v.astype(np.float32))
         return out.reshape(b, c, hq, d)
 
-    @staticmethod
-    def _case(c):
-        """Random q and dense k/v, with k/v also scattered into a pool."""
+    #: Layers in the stacked test pools; the cases read the first and the
+    #: last, and every other layer holds different values, so a wrong
+    #: layer index fails.
+    LAYERS = 3
+
+    @classmethod
+    def _case(cls, c, layer):
+        """Random q and dense k/v, with k/v also scattered into layer
+        ``layer`` of stacked token-major pools (L, NB, BS, Hkv*D)."""
         rng = np.random.RandomState(0)
         b, hq, hkv, d, bs, nb, mb = 2, 4, 2, 16, 4, 12, 4
         kv_len = np.array([6, 11], np.int32)
@@ -144,55 +150,60 @@ class TestPagedAttentionOp:
         q = rng.randn(b, c, hq, d).astype(np.float32)
         dense_k = rng.randn(b, mb * bs, hkv, d).astype(np.float32)
         dense_v = rng.randn(b, mb * bs, hkv, d).astype(np.float32)
-        k_pool = np.zeros((nb, hkv, bs, d), np.float32)
-        v_pool = np.zeros((nb, hkv, bs, d), np.float32)
+        k_pool = rng.randn(cls.LAYERS, nb, bs, hkv * d).astype(np.float32)
+        v_pool = rng.randn(cls.LAYERS, nb, bs, hkv * d).astype(np.float32)
         table = np.full((b, mb), nb, np.int32)
         nxt = 0
         for r in range(b):
             for j in range(mb):
                 table[r, j] = nxt
-                k_pool[nxt] = dense_k[r, j * bs:(j + 1) * bs].swapaxes(0, 1)
-                v_pool[nxt] = dense_v[r, j * bs:(j + 1) * bs].swapaxes(0, 1)
+                rows = slice(j * bs, (j + 1) * bs)
+                k_pool[layer, nxt] = dense_k[r, rows].reshape(bs, -1)
+                v_pool[layer, nxt] = dense_v[r, rows].reshape(bs, -1)
                 nxt += 1
         return q, dense_k, dense_v, k_pool, v_pool, table, q_pos, kv_len
 
+    @pytest.mark.parametrize("layer", [0, LAYERS - 1])
     @pytest.mark.parametrize("c,window", [(1, None), (4, None), (4, 8)])
-    def test_matches_dense_oracle(self, c, window):
+    def test_matches_dense_oracle(self, c, window, layer):
         q, dense_k, dense_v, k_pool, v_pool, table, q_pos, kv_len = \
-            self._case(c)
+            self._case(c, layer)
         got = kops.paged_decode_attention(
             jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(table), jnp.asarray(q_pos), jnp.asarray(kv_len),
-            window=window, backend="xla")
+            jnp.int32(layer), jnp.asarray(table), jnp.asarray(q_pos),
+            jnp.asarray(kv_len), window=window, backend="xla")
         want = self._dense_oracle(q, dense_k, dense_v, q_pos, kv_len,
                                   window)
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
-    def test_kernel_backend_takes_decode_sites(self):
+    @pytest.mark.parametrize("layer", [0, LAYERS - 1])
+    def test_kernel_backend_takes_decode_sites(self, layer):
         """The int32 block table is no operand of the site, so a
         single-token site passes the kernel backends' dtype gate."""
         from repro.backends import registry
         q, dense_k, dense_v, k_pool, v_pool, table, q_pos, kv_len = \
-            self._case(1)
+            self._case(1, layer)
         with registry.record_sites() as sites:
             got = kops.paged_decode_attention(
                 jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-                jnp.asarray(table), jnp.asarray(q_pos), jnp.asarray(kv_len),
-                backend="interpret")
+                jnp.int32(layer), jnp.asarray(table), jnp.asarray(q_pos),
+                jnp.asarray(kv_len), backend="interpret")
         assert [s["backend"] for s in sites] == ["interpret"]
         want = self._dense_oracle(q, dense_k, dense_v, q_pos, kv_len)
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
-    def test_sentinel_rows_stay_finite(self):
+    @pytest.mark.parametrize("layer", [0, LAYERS - 1])
+    def test_sentinel_rows_stay_finite(self, layer):
         """A fully-masked padding row (all-sentinel table, kv_len 0) must
         produce finite output, not NaN."""
         nb, hkv, bs, d = 4, 2, 4, 16
         q = jnp.ones((1, 1, 4, d), jnp.float32)
-        pool = jnp.zeros((nb, hkv, bs, d), jnp.float32)
+        pool = jnp.zeros((self.LAYERS, nb, bs, hkv * d), jnp.float32)
         table = jnp.full((1, 2), nb, jnp.int32)
         out = kops.paged_decode_attention(
-            q, pool, pool, table, jnp.zeros((1, 1), jnp.int32),
-            jnp.zeros((1,), jnp.int32), backend="xla")
+            q, pool, pool, jnp.int32(layer), table,
+            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32),
+            backend="xla")
         assert np.isfinite(np.asarray(out)).all()
 
 
