@@ -2,7 +2,7 @@
 
 After the window has closed, a sample of the requests that the window
 finished, drawn from the seed and always holding the longest, is run
-through the float32 reference (:mod:`bench.reference.dense`): each prompt
+through the float32 reference (``bench/reference/<kind>.py``): each prompt
 with the tokens that were served after it.  At every served position the
 gap is how far the served token's reference logit lies below the
 reference's best.  Greedy decoding that computes what the reference

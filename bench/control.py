@@ -9,7 +9,7 @@ One process builds the cell's engine once.  For each seed it makes the
 weights, serves a window of the cell's own traffic at its own load, and
 checks the finished requests as a benchmark run does.  On the same sample
 it also runs the control: the reference computed in int8
-(:mod:`bench.reference.dense`), read at every served position as the gap
+(``bench/reference/<kind>.py``), read at every served position as the gap
 of the token the int8 forward puts first, and held to the cell's limits
 like the program.  One JSON line per seed gives both gaps, widest and
 mean (the program's are lower readings of the limits, the control's
@@ -33,14 +33,13 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 def readings(cell, seed: int, seconds: float, engine, peaks) -> dict:
     """Serve one window with the seed's weights, then read both gaps."""
-    from bench import check, weights
+    from bench import check, spec
     from bench.generator import arrivals
-    from bench.reference.dense import Reference
     from bench.serve import Window
     conf = cell.config
     engine.state = engine.params = None
     gc.collect()
-    engine.params = weights.program_params(seed, conf)
+    engine.params = spec.model_kind(conf).program_params(seed, conf)
     engine.reset()
     win = Window(engine, arrivals(cell.traffic, seed, conf["vocab_size"],
                                   engine.max_batch), seconds)
@@ -53,7 +52,8 @@ def readings(cell, seed: int, seconds: float, engine, peaks) -> dict:
                           cell.checks["sample_requests"])
     seqs, rows = check.sequences(picked)
     t0 = time.perf_counter()
-    ref = Reference(conf, seed, conf["serving"]["max_seq_len"])
+    ref = spec.reference(conf).Reference(conf, seed,
+                                         conf["serving"]["max_seq_len"])
     lg = ref.logits(seqs, rows, quants=(None, "int8"))
     limits = cell.checks["limits"]
     gaps = {"served": check.served_gaps(lg[None], picked),

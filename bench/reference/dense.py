@@ -6,7 +6,8 @@ published Llama/Mistral convention), causal grouped-query attention, the
 output projection, RMSNorm, and a SwiGLU MLP, each with a residual; then a
 final RMSNorm and the untied head.  Every matrix product runs in float32
 under ``precision="highest"``.  It imports nothing of the program: the
-weights come from :mod:`bench.weights` and the seed, one layer at a time.
+weights come from the seed, one layer at a time, drawn as
+``bench/models/dense.py`` draws the program's.
 
 ``quant="int8"`` computes the same forward with every projection, MLP and
 head product in int8, weights quantized per output column and activations
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import spec
 from bench import weights as W
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -88,7 +90,8 @@ class Reference:
         self.m = m
         self.key = W.root_key(seed)
         self.seq_len = -(-seq_len // QBLOCK) * QBLOCK
-        d, ff, hq, hkv, hd, vocab, layers = W.dims(m)
+        kind = spec.model_kind(m)
+        d, ff, hq, hkv, hd, vocab, layers = kind.dims(m)
         eps, theta = m["rms_norm_eps"], m["rope_theta"]
 
         def layer_fwd(x, w, quant):
@@ -114,7 +117,7 @@ class Reference:
 
         self._layer = jax.jit(layer_fwd, static_argnames="quant")
         self._head = jax.jit(head_fwd, static_argnames="quant")
-        self._gen_layer = jax.jit(lambda key, i: W.layer(key, m, i))
+        self._gen_layer = jax.jit(lambda key, i: kind.layer(key, m, i))
         self._embed = jax.jit(lambda key: W.embed(key, m))
         self._final = jax.jit(lambda key: (W.final_norm(key, m),
                                            W.head(key, m)))

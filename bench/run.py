@@ -14,7 +14,7 @@ result carries the cell's end-to-end metrics; with ``--trace 1`` the last
 :data:`TRACE_SECONDS` of the window run under the profiler and the result
 carries the per-layer metrics, the device's busy time and a breakdown.
 After the window, a sample of the finished requests is checked against
-the float32 reference (:mod:`bench.check`).
+the float32 reference of the configuration's kind (:mod:`bench.check`).
 
 The last line of standard output is one JSON object; the numbers compared
 for ``correct`` come last in it and in the last lines of standard error.
@@ -83,7 +83,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     """Set up, serve the window, check, and build the result line."""
     from bench import check, e2e, serve, spec, trace_reduce
     from bench.generator import arrivals
-    from bench.reference.dense import Reference
 
     conf = cell.config
     engine = serve.build(conf, seed)
@@ -108,7 +107,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     picked = check.sample(finished, seed, cell.checks["sample_tokens"],
                           cell.checks["sample_requests"])
     seqs, rows = check.sequences(picked)
-    ref = Reference(conf, seed, conf["serving"]["max_seq_len"])
+    ref = spec.reference(conf).Reference(conf, seed,
+                                         conf["serving"]["max_seq_len"])
     gaps = check.served_gaps(ref.logits(seqs, rows)[None], picked)
     nums = check.numbers(gaps, failed, limits)
     correct = check.passed(nums)
@@ -169,7 +169,7 @@ def main(argv=None) -> int:
         from bench import spec
         try:
             cell = spec.cell(args.workload)
-        except (KeyError, FileNotFoundError) as exc:
+        except (KeyError, FileNotFoundError, ValueError) as exc:
             raise Refused(f"no such cell: {exc}") from exc
         compile_cache()
         devices, peaks = find_chips(cell.chips)

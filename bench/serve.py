@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from bench import weights as W
+from bench import spec
 from bench.record import Record, Req, Tick
 
 #: Serving state is the engine call's argument 1: donated, so a step
@@ -29,21 +29,12 @@ DONATE_STATE = (1,)
 
 def model_config(conf: Dict[str, Any]):
     """The program's ``ModelConfig`` for a configuration file, checked
-    against the file's published sizes."""
+    against the fields that the file's kind (``bench/models/<kind>.py``)
+    says its published sizes fix."""
     from repro.configs import get_config
     cfg = dataclasses.replace(get_config(conf["model"]["repro_config"]),
                               **conf["model"]["overrides"])
-    want = {"d_model": conf["hidden_size"],
-            "d_ff": conf["intermediate_size"],
-            "num_heads": conf["num_attention_heads"],
-            "num_kv_heads": conf["num_key_value_heads"],
-            "resolved_head_dim": conf["head_dim"],
-            "vocab_size": conf["vocab_size"],
-            "num_layers": conf["num_hidden_layers"],
-            "rope_theta": conf["rope_theta"],
-            "block_pattern": ("attn",), "moe": None,
-            "param_dtype": conf["torch_dtype"],
-            "dtype": conf["torch_dtype"]}
+    want = spec.model_kind(conf).expected(conf)
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise ValueError(f"{conf['name']}: the program's config {got} is "
@@ -56,7 +47,7 @@ def build(conf: Dict[str, Any], seed: int, cfg=None):
     from repro.api import SMAOptions
     from repro.serving import CacheConfig, SchedulerConfig, ServeEngine
     cfg = cfg or model_config(conf)
-    params = W.program_params(seed, conf)
+    params = spec.model_kind(conf).program_params(seed, conf)
     jax.block_until_ready(params)
     srv = conf["serving"]
     engine = ServeEngine(

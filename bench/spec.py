@@ -5,7 +5,19 @@ Each lives in a file of its own, found by name, so a later change adds a
 configuration, a mix, a cell or a per-layer metric by adding files:
 
 * ``bench/configs/<config>.json``: the model as it runs, its source, what
-  was cut and assumed, and the serving settings;
+  was cut and assumed, and the serving settings; its ``model.kind``
+  (``dense`` where it names none) picks the next two;
+* ``bench/models/<kind>.py``: what the benchmark knows of that kind's
+  block layout: ``expected(conf)``, the ``ModelConfig`` fields the program
+  must match; ``program_params(seed, conf)``, the program's weights from
+  the seed, and ``layer(key, conf, index)``, one layer of them;
+  ``gemm_least_time(conf, tokens, peaks)``,
+  ``tick_flops(conf, rows, logit_rows)`` and
+  ``attention_least_time(conf, kv_lens, peaks)``, the work the rooflines
+  and ``step_mfu`` count; ``tiny(conf, control=False)``, the cut the CPU
+  tests run;
+* ``bench/reference/<kind>.py``: ``Reference(conf, seed, seq_len)``, the
+  plain forward whose ``logits(seqs, rows, quants)`` decides ``correct``;
 * ``bench/traffic/<traffic>.json``: the parameters that the one general
   generator (:mod:`bench.generator`) reads;
 * ``bench/traffic/<kind>.py``: one module per arrival kind, named by a
@@ -76,6 +88,27 @@ def arrival_kind(name: str):
     return _module("traffic", name)
 
 
+def model_kind(conf: Dict[str, Any]):
+    """The module ``bench/models/<kind>.py`` of a configuration's kind."""
+    return _module("models", kind(conf))
+
+
+def reference(conf: Dict[str, Any]):
+    """The module ``bench/reference/<kind>.py`` of a configuration's kind."""
+    return _module("reference", kind(conf))
+
+
+def kind(conf: Dict[str, Any]) -> str:
+    """The configuration's model kind, refused unless both of its modules
+    are there."""
+    name = conf.get("model", {}).get("kind", "dense")
+    for sub in ("models", "reference"):
+        if not (BENCH / sub / f"{name}.py").is_file():
+            raise ValueError(f"unknown model kind {name!r}: no "
+                             f"bench/{sub}/{name}.py")
+    return name
+
+
 def _module(sub: str, name: str):
     path = BENCH / sub / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
@@ -116,7 +149,8 @@ def cell(name: str) -> Cell:
     e2e_names = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"]
              if _applies(m, name) and m["moves"] in e2e_names]
-    return Cell(name=name, chips=entry["chips"],
-                config=config(entry["config"]),
+    conf = config(entry["config"])
+    kind(conf)
+    return Cell(name=name, chips=entry["chips"], config=conf,
                 traffic=traffic(entry["traffic"]), checks=checks(name),
                 end_to_end=e2e, per_layer=layer)

@@ -5,10 +5,13 @@ a GEMM's rows are the real tokens of the tick, and decode attention reads
 each row's keys and values up to its live ``kv_len``.  So two programs
 that implement the same site differently are held to the same work.
 Everything is in bf16 (2 bytes), the type the configurations serve in.
+
+These are the calls every model kind is made of; what one tick of a kind
+adds up to is in ``bench/models/<kind>.py``.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Tuple
+from typing import Dict, Iterable, Tuple
 
 BYTES = 2
 
@@ -32,52 +35,6 @@ def decode_attention(kv_lens: Iterable[int], heads: int, kv_heads: int,
     return flops, nbytes
 
 
-def layer_gemms(m: Dict) -> List[Tuple[int, int]]:
-    """(K, N) of the GEMMs of one dense ``attn`` layer: q, k, v, o, and
-    the gated MLP's in, gate and out."""
-    d, ff = m["hidden_size"], m["intermediate_size"]
-    hq, hkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   m["head_dim"])
-    return [(d, hq * hd), (d, hkv * hd), (d, hkv * hd), (hq * hd, d),
-            (d, ff), (d, ff), (ff, d)]
-
-
-def gemm_least_time(m: Dict, tokens: int, peaks: Dict) -> float:
-    """Least seconds one tick's layer GEMMs could take on the chip, over
-    its ``tokens`` real rows: each call bounded by the larger of its FLOPs
-    over peak and its bytes over bandwidth."""
-    t = 0.0
-    for k, n in layer_gemms(m):
-        f, b = gemm(tokens, k, n)
-        t += m["num_hidden_layers"] * least_time(f, b, peaks)
-    return t
-
-
 def least_time(flops: float, nbytes: float, peaks: Dict) -> float:
     return max(flops / peaks["bf16_flops_per_s"],
                nbytes / peaks["hbm_bytes_per_s"])
-
-
-def layer_params(m: Dict) -> int:
-    return sum(k * n for k, n in layer_gemms(m))
-
-
-def tick_flops(m: Dict, rows, logit_rows: int) -> float:
-    """Model FLOPs of one tick, ``rows`` as ``(start, n)`` real positions:
-    per position 2 x the layer parameters it multiplies and QK and PV over
-    its context, plus the head for each of ``logit_rows``."""
-    layers = m["num_hidden_layers"]
-    per_pos = 2.0 * layers * layer_params(m)
-    attn = 4.0 * layers * m["num_attention_heads"] * m["head_dim"]
-    f = 0.0
-    for start, n in rows:
-        contexts = n * start + n * (n + 1) // 2
-        f += per_pos * n + attn * contexts
-    return f + logit_rows * 2.0 * m["hidden_size"] * m["vocab_size"]
-
-
-def attention_least_time(m: Dict, kv_lens, peaks: Dict) -> float:
-    """Least seconds of one decode tick's attention over every layer."""
-    f, b = decode_attention(kv_lens, m["num_attention_heads"],
-                            m["num_key_value_heads"], m["head_dim"])
-    return m["num_hidden_layers"] * least_time(f, b, peaks)

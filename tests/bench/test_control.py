@@ -2,15 +2,16 @@
 precision below the configured bf16, put in the program's place.
 
 On the chip this is read at each cell's own size (``bench/control.py``,
-readings in PERF.md).  Here it runs at a size a test holds: 8 layers of
-width 512 with each configuration's head layout, a 32k vocabulary and
-four sequences of 1024 tokens, held to each cell's own limit."""
+readings in PERF.md).  Here it runs at a size a test holds: each
+configuration at its kind's control cut (``tiny(conf, control=True)``;
+the dense kind's is 8 layers of width 512 with the configuration's head
+layout and a 32k vocabulary), over four sequences of 1024 tokens, held
+to each cell's own limit."""
 import numpy as np
 import pytest
 
 from bench import check, spec
-from bench.reference.dense import Reference
-from conftest import tiny_config
+from conftest import CONFIGS, tiny_config
 
 CELLS = [w["name"] for w in spec.benchmark()["workloads"]]
 
@@ -18,17 +19,14 @@ CELLS = [w["name"] for w in spec.benchmark()["workloads"]]
 @pytest.fixture(scope="module")
 def readings():
     out = {}
-    for name in ("stablelm-1.6b", "mistral-nemo-12b"):
-        conf = tiny_config(name)
-        conf.update(hidden_size=512, intermediate_size=1408,
-                    vocab_size=32768, num_hidden_layers=8,
-                    head_dim=512 // conf["num_attention_heads"])
+    for name in CONFIGS:
+        conf = tiny_config(name, control=True)
         rng = np.random.default_rng(0)
         seqs = [rng.integers(0, conf["vocab_size"], 1024, dtype=np.int32)
                 for _ in range(4)]
         rows = [np.arange(24, 1024)] * 4
-        lg = Reference(conf, 7, 1024).logits(seqs, rows,
-                                             quants=(None, "int8"))
+        ref = spec.reference(conf).Reference(conf, 7, 1024)
+        lg = ref.logits(seqs, rows, quants=(None, "int8"))
         out[name] = (check.control_gaps(lg[None], lg["int8"]),
                      check.served_gaps(lg[None], [
                          {"tokens": g.argmax(-1)} for g in lg[None]]))

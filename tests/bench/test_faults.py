@@ -12,10 +12,11 @@ import time
 import jax
 import pytest
 
-from bench import faults, run, serve
+from bench import faults, run, serve, spec
 from conftest import PEAKS, tiny_cell
 
-CELL = "stablelm-1.6b.decode-backlog"
+#: The first cell of BENCHMARK.json, cut to CPU size by its kind.
+CELL = spec.benchmark()["workloads"][0]["name"]
 SEED = 2 ** 31 + 21
 
 

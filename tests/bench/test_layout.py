@@ -60,6 +60,25 @@ def test_per_layer_metric_cells_report_what_it_moves(metric):
                                   spec.cell(cell).per_layer}
 
 
+#: What a model kind's two modules give (``bench/spec.py`` says what each
+#: name is).
+KIND_NAMES = {"models": ("expected", "program_params", "layer",
+                         "gemm_least_time", "tick_flops",
+                         "attention_least_time", "tiny"),
+              "reference": ("Reference",)}
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_every_config_kind_resolves_to_both_modules(name):
+    conf = spec.config(name)
+    for sub, mod in (("models", spec.model_kind(conf)),
+                     ("reference", spec.reference(conf))):
+        assert mod.__file__ == str(spec.BENCH / sub /
+                                   f"{spec.kind(conf)}.py")
+        for attr in KIND_NAMES[sub]:
+            assert callable(getattr(mod, attr)), (sub, attr)
+
+
 def test_config_files_state_their_cuts():
     for entry in BENCH["configs"]:
         conf = json.loads((ROOT / entry["file"]).read_text())

@@ -2,23 +2,22 @@
 nothing where the run holds nothing to read."""
 import pytest
 
-from bench import spec, work
+from bench import spec
 from bench import trace_reduce as TR
 from bench.record import Record, Tick
+from conftest import CONFIGS, tiny_config
 
-MODEL = {"hidden_size": 64, "intermediate_size": 128,
-         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-         "vocab_size": 256, "num_hidden_layers": 2}
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
 NS = 1_000_000     # 1 ms in the trace's nanoseconds
 
 
-def _record(trace):
+def _record(trace, config=CONFIGS[0]):
     ticks = [Tick(0.0, 0.010, "prefill", [(0, 32), (0, 20)], 1, True),
              Tick(0.010, 0.014, "decode", [(20, 1), (32, 1)], 2, True),
              Tick(0.014, 0.020, "decode", [(21, 1)], 1, True)]
-    return Record(model=MODEL, peaks=PEAKS, t_start=0.0, t_end=0.02,
-                  ticks=ticks, requests={}, compiles=0, trace=trace)
+    return Record(model=tiny_config(config), peaks=PEAKS, t_start=0.0,
+                  t_end=0.02, ticks=ticks, requests={}, compiles=0,
+                  trace=trace)
 
 
 def _trace():
@@ -43,19 +42,22 @@ def test_host_readers():
     assert read("window_compiles", rec) == 0
 
 
-def test_trace_readers():
-    rec = _record(_trace())
+@pytest.mark.parametrize("config", CONFIGS)
+def test_trace_readers(config):
+    rec = _record(_trace(), config)
     # Busy 0-6 ms and 12-13 ms of 20.
     assert read("device_idle_share", rec) == pytest.approx(100 * 13 / 20)
-    least = sum(work.gemm_least_time(MODEL, t.tokens, PEAKS)
+    # The work counts are the configuration's kind's.
+    kind = spec.model_kind(rec.model)
+    least = sum(kind.gemm_least_time(rec.model, t.tokens, PEAKS)
                 for t in rec.ticks)
     assert read("sma_gemm_roofline", rec) == pytest.approx(
         100 * least / 6e-3)
-    attn = sum(work.attention_least_time(MODEL, t.kv_lens, PEAKS)
+    attn = sum(kind.attention_least_time(rec.model, t.kv_lens, PEAKS)
                for t in rec.ticks if t.phase == "decode")
     assert read("paged_decode_attn_roofline", rec) == pytest.approx(
         100 * attn / 1e-3)
-    flops = sum(work.tick_flops(MODEL, t.rows, t.logit_rows)
+    flops = sum(kind.tick_flops(rec.model, t.rows, t.logit_rows)
                 for t in rec.ticks)
     assert read("step_mfu", rec) == pytest.approx(100 * flops / 20e-3 / 1e12)
 

@@ -1,26 +1,25 @@
-"""The plain reference against the program at CPU size, for both
-configurations: the same weights, then the logits every served token was
-chosen from."""
+"""The plain reference against the program at CPU size, for every
+configuration of BENCHMARK.json, each through its kind's modules: the same
+weights, then the logits every served token was chosen from."""
 import jax
 import numpy as np
 import pytest
 
-from bench import check, serve
+from bench import check, serve, spec
 from bench import weights as W
-from bench.reference.dense import Reference
-from conftest import tiny_config
+from conftest import CONFIGS, tiny_config
 
-CONFIGS = ["stablelm-1.6b", "mistral-nemo-12b"]
 SEED = 2 ** 33 + 3
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_stacked_weights_are_the_per_layer_weights(name):
     conf = tiny_config(name)
-    params = W.program_params(SEED, conf)
+    kind = spec.model_kind(conf)
+    params = kind.program_params(SEED, conf)
     key = W.root_key(SEED)
     for i in range(conf["num_hidden_layers"]):
-        one = W.layer(key, conf, i)
+        one = kind.layer(key, conf, i)
         stacked = jax.tree.map(lambda a: a[i], params["blocks"][0])
         same = jax.tree.map(lambda a, b: bool((a == b).all()), one, stacked)
         assert all(jax.tree.leaves(same))
@@ -57,7 +56,8 @@ def test_reference_agrees_with_serve_engine(name):
              "tokens": list(r.out_tokens)} for r in engine.done.values()]
     assert len(done) == 3 and not engine.failed
     seqs, pos = check.sequences(done)
-    ref = Reference(conf, SEED, conf["serving"]["max_seq_len"])
+    ref = spec.reference(conf).Reference(conf, SEED,
+                                         conf["serving"]["max_seq_len"])
     logits = ref.logits(seqs, pos)[None]
     for r, want in zip(done, logits):
         got = np.stack(seen[r["rid"]]).astype(np.float32)

@@ -1,12 +1,15 @@
-"""Operations and bytes per kernel call against counts made by hand."""
+"""Operations and bytes per kernel call against counts made by hand: the
+calls of :mod:`bench.work`, and the dense kind's tick (a file that names
+no kind is dense)."""
 import pytest
 
-from bench import work
+from bench import spec, work
 
 MISTRAL = {"hidden_size": 5120, "intermediate_size": 14336,
            "num_attention_heads": 32, "num_key_value_heads": 8,
            "head_dim": 128, "vocab_size": 131072, "num_hidden_layers": 5}
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+DENSE = spec.model_kind(MISTRAL)
 
 
 def test_gemm_by_hand():
@@ -33,15 +36,15 @@ def test_decode_attention_reads_live_kv_only():
 def test_tick_flops_by_hand():
     # A prefill chunk of 3 tokens at positions 5-7 (contexts 6, 7, 8),
     # logits at its last.
-    layers, params = 5, work.layer_params(MISTRAL)
+    layers, params = 5, DENSE.layer_params(MISTRAL)
     dense = 3 * 2.0 * layers * params
     attn = 4.0 * layers * 32 * 128 * (6 + 7 + 8)
     head = 2.0 * 5120 * 131072
-    assert work.tick_flops(MISTRAL, [(5, 3)], 1) == pytest.approx(
+    assert DENSE.tick_flops(MISTRAL, [(5, 3)], 1) == pytest.approx(
         dense + attn + head)
 
 
 def test_layer_gemms_cover_the_layer():
     d, ff, q, kv = 5120, 14336, 32 * 128, 8 * 128
-    assert work.layer_params(MISTRAL) == (d * q + 2 * d * kv + q * d
-                                          + 3 * d * ff)
+    assert DENSE.layer_params(MISTRAL) == (d * q + 2 * d * kv + q * d
+                                           + 3 * d * ff)
